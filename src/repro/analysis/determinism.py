@@ -22,11 +22,11 @@ permutation-dependent and is flagged with the connecting path.
 block shape derived from the data-dependent slab length recompiles per
 length *and* changes the reduction tree shape, so the same records can
 sum to different floats depending on how full the slab is.
-:func:`check_slab_invariance` traces the fused gather+segment-reduce
-kernel builder at two slab lengths and requires the 1-D operand shapes
-of every ``pallas_call`` to be identical — with the fixed
-``block_tokens`` both pad to the same block; a length-derived block
-leaks the length into the operands.
+:func:`check_slab_invariance` traces the gather + segment-reduce kernel
+builder at two slab lengths and requires the operand shapes of every
+``pallas_call`` to be identical — with the fixed ``block_tokens`` both
+pad to the same block; a length-derived block leaks the length into the
+operands.
 """
 
 from __future__ import annotations
@@ -114,17 +114,17 @@ def _check_wire_sorts(name: str, g: EqnGraph, coded: bool) -> List[Finding]:
 
 
 def _default_slab_build(n: int):
-    """Trace the fused gather+segment-reduce kernel at slab length ``n``."""
+    """Trace the gather + sorted segment-reduce kernel at slab length ``n``."""
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.fused_shuffle_reduce.fused_shuffle_reduce import (
-        fused_gather_segment_reduce_pallas,
+    from repro.kernels.segment_reduce.segment_reduce import (
+        segment_reduce_sorted_pallas,
     )
 
     def body(values, gather_idx, seg_ids):
-        return fused_gather_segment_reduce_pallas(
-            values, gather_idx, seg_ids, num_segments=8, interpret=True)
+        return segment_reduce_sorted_pallas(
+            values[gather_idx], seg_ids, num_segments=8, interpret=True)
 
     return jax.make_jaxpr(body)(
         jax.ShapeDtypeStruct((n, 3), jnp.float32),
@@ -133,23 +133,21 @@ def _default_slab_build(n: int):
     )
 
 
-def _pallas_operand_shapes_1d(closed) -> List[tuple]:
-    """Sorted 1-D operand shapes of every pallas_call in a traced program.
+def _pallas_operand_shapes(closed) -> List[tuple]:
+    """Sorted operand shapes of every pallas_call in a traced program.
 
-    The 1-D operands are the token-indexed slabs (gather indices, segment
-    ids, padded token columns); with a fixed ``block_tokens`` they are
-    padded to the block and their shapes do not depend on the slab
-    length. Higher-rank operands (the value table) legitimately scale
-    with the input and are excluded.
+    The kernel's operands are the token-indexed slabs (gathered values,
+    segment ids) and the per-block walk derived from them; with a fixed
+    ``block_tokens`` they are padded to the block and their shapes do not
+    depend on the slab length.
     """
     shapes = []
     for eqn, _path in iter_eqns_recursive(closed.jaxpr):
         if eqn.primitive.name != "pallas_call":
             continue
         for v in eqn.invars:
-            aval = getattr(v, "aval", None)
-            shape = getattr(aval, "shape", None)
-            if shape is not None and len(shape) == 1:
+            shape = getattr(getattr(v, "aval", None), "shape", None)
+            if shape is not None:
                 shapes.append(tuple(shape))
     return sorted(shapes)
 
@@ -158,28 +156,28 @@ def check_slab_invariance(build: Optional[Callable] = None) -> List[Finding]:
     """D3: kernel blocking must not depend on the data-dependent slab length.
 
     ``build(n)`` must return the traced (ClosedJaxpr) kernel program for
-    slab length ``n``; defaults to the repo's fused gather+segment-reduce
-    builder. Traces at two lengths below one block and compares the 1-D
+    slab length ``n``; defaults to the repo's gather + segment-reduce
+    builder. Traces at two lengths below one block and compares the
     operand shapes of every ``pallas_call``.
     """
     build = build or _default_slab_build
     n_a, n_b = _SLAB_LENGTHS
-    shapes_a = _pallas_operand_shapes_1d(build(n_a))
-    shapes_b = _pallas_operand_shapes_1d(build(n_b))
+    shapes_a = _pallas_operand_shapes(build(n_a))
+    shapes_b = _pallas_operand_shapes(build(n_b))
     if shapes_a == shapes_b:
         return []
     return [Finding(
         checker="determinism",
         rule="slab-dependent-blocking",
-        target="fused_gather_segment_reduce",
+        target="gather_segment_reduce",
         summary=(
             "pallas_call operand shapes change with the slab length — "
             "blocking derives from data-dependent length, so the "
             "reduction tree (and its float rounding) varies per slab "
             "(PR 8 bug class)"),
         evidence=[
-            f"slab length {n_a}: 1-D operands {shapes_a}",
-            f"slab length {n_b}: 1-D operands {shapes_b}",
+            f"slab length {n_a}: operands {shapes_a}",
+            f"slab length {n_b}: operands {shapes_b}",
             "a fixed block_tokens pads both lengths to identical blocks",
         ],
     )]

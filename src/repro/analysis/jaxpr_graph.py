@@ -2,15 +2,15 @@
 
 The contract checkers reason about *traced programs*, not running ones:
 :func:`trace_sharded` traces a per-shard phase-B body under the engine's
-named axis (``jax.make_jaxpr`` inside ``extend_axis_env_nd`` — the
+named axis (``jax.make_jaxpr`` with an ``axis_env`` binding it — the
 collectives ``all_to_all`` / ``psum`` / ``axis_index`` stay first-class
 equations instead of being rewritten by a transform), and
 :class:`EqnGraph` turns the result into one flat producer→consumer DAG.
 
-Flattening matters: ``jnp.argsort`` and friends lower into ``pjit``
+Flattening matters: ``jnp.argsort`` and friends lower into ``jit``
 *sub-jaxprs*, so a top-level walk never sees a ``sort`` equation. The
-graph builder therefore **inlines** call-like equations (``pjit``,
-``closed_call``, ``custom_jvp_call``/``custom_vjp_call``, ``remat``,
+graph builder therefore **inlines** call-like equations (``jit``,
+``closed_call``, ``custom_jvp_call``/``custom_vjp_call``, ``remat2``,
 ``shard_map``), threading producers through the call boundary, and keeps
 everything else (``pallas_call``, control flow) as one opaque node whose
 outputs depend on all of its inputs — conservative in exactly the safe
@@ -27,13 +27,12 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 # Call-like primitives whose sub-jaxpr is semantically inline code.
 _INLINE_PRIMS = {
-    "pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
-    "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
-    "custom_vjp_call_jaxpr", "shard_map",
+    "jit", "closed_call", "remat2", "custom_jvp_call", "custom_vjp_call",
+    "shard_map",
 }
 
 
@@ -47,8 +46,7 @@ def trace_sharded(fn, args, axis_name: str, axis_size: int):
     ``shard_map`` — without standing up devices or letting a transform's
     batching rule rewrite the collectives.
     """
-    with jcore.extend_axis_env_nd([(axis_name, axis_size)]):
-        return jax.make_jaxpr(fn)(*args)
+    return jax.make_jaxpr(fn, axis_env=[(axis_name, axis_size)])(*args)
 
 
 def _sub_jaxpr(params) -> Optional[jcore.Jaxpr]:

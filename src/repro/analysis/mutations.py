@@ -155,14 +155,14 @@ def _mutant_rogue_callback():
 
 def _mutant_slab_blocking():
     """Kernel builder whose block size tracks the slab length (PR 8 bug)."""
-    from repro.kernels.fused_shuffle_reduce.fused_shuffle_reduce import (
-        fused_gather_segment_reduce_pallas,
+    from repro.kernels.segment_reduce.segment_reduce import (
+        segment_reduce_sorted_pallas,
     )
 
     def build(n: int):
         def body(values, gather_idx, seg_ids):
-            return fused_gather_segment_reduce_pallas(
-                values, gather_idx, seg_ids, num_segments=8,
+            return segment_reduce_sorted_pallas(
+                values[gather_idx], seg_ids, num_segments=8,
                 block_tokens=max(8, n),          # BUG: length-derived block
                 interpret=True)
 

@@ -1,74 +1,29 @@
-"""Version-adaptive jax API shims.
+"""Shared spellings of the jax mesh API (jax >= 0.9).
 
-The substrate targets the current jax API (``jax.shard_map``,
-``jax.lax.pvary``, ``jax.sharding.AxisType``) but must also run on the
-0.4.x line this container ships. Every call site goes through this module
-so the divergence lives in exactly one place.
-
-* :func:`shard_map` — ``jax.shard_map`` when present, else
-  ``jax.experimental.shard_map.shard_map``; the replication-check kwarg is
-  translated (``check_vma`` new / ``check_rep`` old).
-* :func:`pvary` — device-variance annotation; identity where the
-  primitive does not exist (older jax infers variance itself).
-* :func:`make_mesh` — ``jax.make_mesh`` with ``axis_types`` when the
-  installed jax knows about explicit axis types, plain otherwise.
+* :func:`shard_map` — ``jax.shard_map`` with the varying-manual-axes
+  check off by default (the engine's bodies mix replicated and varying
+  values on purpose).
+* :func:`make_mesh` — ``jax.make_mesh`` with ``Auto`` axis types, so
+  ``jit`` keeps inserting the sharding constraints the callers rely on.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-import numpy as np
 
-__all__ = ["shard_map", "pvary", "make_mesh", "tpu_compiler_params"]
-
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _check_kwarg = (
-        "check_vma"
-        if "check_vma" in inspect.signature(jax.shard_map).parameters
-        else "check_rep"
-    )
-else:  # jax < 0.6: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-    _check_kwarg = "check_rep"
+__all__ = ["shard_map", "make_mesh"]
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions (replication check off by default)."""
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_check_kwarg: check},
+    """``jax.shard_map`` with the varying-manual-axes check off by default."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check,
     )
 
 
-def pvary(x, axis_name):
-    """Mark ``x`` device-varying over ``axis_name`` (no-op on older jax)."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_name)
-    return x
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the CompilerParams rename."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     shape, axes = tuple(shape), tuple(axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

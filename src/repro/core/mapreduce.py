@@ -17,8 +17,8 @@ chunks with a software-pipelined loop — the all-to-all "copy" of chunk
 ``i+1`` is issued *before* the segment-reduce "run" of chunk ``i``, so on
 real hardware the ICI transfer of the next chunk overlaps the current
 chunk's compute (the TPU analogue of Fig 4(b)'s copy/sort/run overlap).
-The "sort" and "run" of a chunk are fused into a single pass by
-``kernels/fused_shuffle_reduce`` when ``use_kernels=True``.
+With ``use_kernels=True`` the "sort" and "run" of a chunk are one
+gather into rank order plus the ``kernels/segment_reduce`` Pallas kernel.
 
 Schedule selection: ``scheduler`` may name one algorithm (``hash`` | ``lpt``
 | ``multifit`` | ``bss`` | ``os4m``) or ``"auto"``, which runs every
@@ -57,6 +57,7 @@ import collections
 import dataclasses
 import functools
 import time
+import warnings
 from typing import Callable, Optional, Tuple
 
 import numpy as np
@@ -119,7 +120,7 @@ class MapReduceConfig:
     pipeline_chunks: int = 4            # Reduce pipeline granularity (§4.4)
     pipelined: bool = True              # False = Hadoop-style single-shot phase B
     capacity_send: Optional[int] = None  # per-(shard,dest) send buffer; None = safe bound
-    use_kernels: bool = False           # route histogram/fused shuffle-reduce via Pallas
+    use_kernels: bool = False           # route statistics + chunk reduce via Pallas
     reuse: Optional[sc.ReusePolicy] = None  # schedule-reuse policy; None = replan per run
     speeds: Optional[Tuple[float, ...]] = None  # static per-slot speeds (1.0 = nominal)
     estimate_speeds: bool = False       # learn speeds online from phase-B timings
@@ -325,28 +326,15 @@ def _ragged_counting_sort_to_buckets(
     return bucket_values, bucket_clusters, bucket_valid, overflow
 
 
-def _segment_reduce(
-    cluster_ids, values, valid, num_clusters: int, reduce_op: str, use_kernel: bool
-):
-    """Reduce the "run" phase: aggregate pairs per cluster."""
+def _segment_reduce(cluster_ids, values, valid, num_clusters: int, reduce_op: str):
+    """Reduce the "run" phase: aggregate pairs per cluster (jnp path)."""
     w = valid.astype(values.dtype)[..., None]
     seg = jnp.where(valid, cluster_ids, num_clusters)
     counts = jax.ops.segment_sum(
         valid.astype(jnp.float32), seg, num_segments=num_clusters + 1
     )[:-1]
     if reduce_op == "sum":
-        if use_kernel:
-            from repro.kernels.segment_reduce import ops as segops
-
-            # Identical-sort wire contract (docs/SHUFFLE.md): stability is
-            # explicit, not an argsort default — every engine path must
-            # order equal keys identically for bit-identical reduces.
-            order = jnp.argsort(seg, stable=True)
-            out = segops.segment_reduce_sorted(
-                (values * w)[order], seg[order].astype(jnp.int32), num_clusters + 1
-            )[:-1]
-        else:
-            out = jax.ops.segment_sum(values * w, seg, num_segments=num_clusters + 1)[:-1]
+        out = jax.ops.segment_sum(values * w, seg, num_segments=num_clusters + 1)[:-1]
     elif reduce_op == "max":
         big_neg = jnp.finfo(values.dtype).min
         masked = jnp.where(valid[:, None], values, big_neg)
@@ -379,8 +367,9 @@ def _reduce_chunk(
 
     Kernel path: pairs are ordered by pipeline *rank* (increasing cluster
     load, §4.4) — rank is the one key that is monotone along the sorted
-    stream — and the fused kernel gathers + segment-reduces in a single
-    pass; the result is un-permuted back to cluster ids with one gather.
+    stream — gathered into that order, and segment-reduced by the sorted
+    segment-sum kernel; the result is un-permuted back to cluster ids with
+    one gather.
 
     jnp path: ``segment_sum`` needs no sorted stream, and each cluster's
     pairs arrive in the same (src shard, bucket position) relative order on
@@ -388,16 +377,14 @@ def _reduce_chunk(
     the explicit sort is skipped entirely.
     """
     if reduce_op == "sum" and use_kernel:
-        from repro.kernels.fused_shuffle_reduce import ops as fused_ops
+        from repro.kernels.segment_reduce import ops as segops
 
         rank = jnp.where(
             rm, rank_of_cluster[jnp.clip(rc, 0, num_clusters - 1)], num_clusters
         )
         order = jnp.argsort(rank, stable=True)
-        rank_sorted = rank[order].astype(jnp.int32)
-        out_by_rank = fused_ops.fused_shuffle_reduce(
-            rv, order.astype(jnp.int32), rank_sorted, num_clusters,
-            use_kernel=True,
+        out_by_rank = segops.segment_reduce_sorted(
+            rv[order], rank[order].astype(jnp.int32), num_clusters
         )
         out = out_by_rank[rank_of_cluster]
         seg = jnp.where(rm, rc, num_clusters)
@@ -405,7 +392,7 @@ def _reduce_chunk(
             rm.astype(jnp.float32), seg, num_segments=num_clusters + 1
         )[:-1]
         return out, counts
-    return _segment_reduce(rc, rv, rm, num_clusters, reduce_op, False)
+    return _segment_reduce(rc, rv, rm, num_clusters, reduce_op)
 
 
 def _sequential_reduce(
@@ -434,7 +421,7 @@ def _sequential_reduce(
     # Identical-sort wire contract: stability explicit, never a default.
     order = jnp.argsort(rank, stable=True)
     return _segment_reduce(
-        rc[order], rv[order], rm[order], num_clusters, reduce_op, False
+        rc[order], rv[order], rm[order], num_clusters, reduce_op
     )
 
 
@@ -913,7 +900,7 @@ def _phase_b_shard(
 
     # ---- Double-buffered copy→run walk, in increasing-load chunk order.
     # Accumulator dtype mirrors what the sequential path returns (f32 from
-    # the fused kernel, else the value dtype) so both paths agree exactly.
+    # the segment-reduce kernel, else the value dtype) so both paths agree.
     acc_dtype = jnp.float32 if (reduce_op == "sum" and use_kernel) else values.dtype
     acc = jnp.zeros((num_clusters, v_dim), acc_dtype)
     cnt = jnp.zeros((num_clusters,), jnp.float32)
@@ -2038,9 +2025,10 @@ class MapReduceJob:
         measurement.
 
         Platforms without a tick source (``wave_timer.ops.available()``
-        False — no device counter primitive and no CPU callback) fall
-        back to :meth:`_execute_measured_fenced`, the documented
-        host-timed path.
+        False — no device counter primitive and no CPU callback, as on a
+        TPU with jax 0.9) fall back to :meth:`_execute_measured_fenced`,
+        the documented host-timed path, and say so with a
+        ``RuntimeWarning``.
 
         Returns ``(out, counts, overflow, timings)`` where ``timings`` is
         the ``(slots, waves)`` :class:`repro.core.mesh_timing.WaveTimings`
@@ -2049,6 +2037,11 @@ class MapReduceJob:
         from repro.kernels.wave_timer import ops as wt_ops
 
         if not wt_ops.available():
+            warnings.warn(
+                f"no wave-timer tick source on the {jax.default_backend()!r} "
+                "backend: measured phase-B timings use the host-fenced executor",
+                RuntimeWarning, stacklevel=2,
+            )
             return self._execute_measured_fenced(intermediate, planned)
         cfg = self.cfg
         m, n = cfg.num_slots, cfg.num_clusters

@@ -28,8 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro import compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _xor_kernel(a_ref, b_ref, o_ref):
@@ -42,7 +41,7 @@ def xor_words_pallas(
     b: jax.Array,            # (N, W) same shape/dtype as ``a``
     *,
     block_rows: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Elementwise ``a ^ b`` over word slabs. Returns ``(N, W)`` words.
 
@@ -74,7 +73,7 @@ def xor_words_pallas(
         ],
         out_specs=pl.BlockSpec((block_rows, w), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((a.shape[0], w), a.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -41,7 +41,7 @@ _BYTES_PER_WORD = 4
 def xor_words(a: jax.Array, b: jax.Array, *, use_kernel: bool = True) -> jax.Array:
     """Elementwise ``a ^ b`` over (N, W) int32/uint32 word slabs."""
     if use_kernel:
-        return xor_words_pallas(a, b, interpret=_k.INTERPRET)
+        return xor_words_pallas(a, b, interpret=_k.interpret())
     return xor_words_ref(a, b)
 
 

@@ -20,7 +20,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: float | None = No
     """(B, Hq, T, D) x (B, Hkv, S, D)^2 -> (B, Hq, T, D)."""
     return flash_attention_pallas(
         q, k, v, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, interpret=_k.INTERPRET,
+        block_q=block_q, block_k=block_k, interpret=_k.interpret(),
     )
 
 

@@ -11,5 +11,5 @@ from repro.kernels.histogram.histogram import histogram_pallas
 def histogram(ids: jax.Array, weights: jax.Array, num_bins: int) -> jax.Array:
     """Weighted histogram of integer ids; the K^(i) vector of paper eq. 4-1."""
     return histogram_pallas(
-        ids.reshape(-1), weights.reshape(-1), num_bins, interpret=_k.INTERPRET
+        ids.reshape(-1), weights.reshape(-1), num_bins, interpret=_k.interpret()
     )

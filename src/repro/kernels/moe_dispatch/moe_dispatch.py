@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _dispatch_kernel(dest_ref, rank_ref, counts_ref, carry_ref, *, num_dests: int,
                      num_blocks: int):
@@ -77,7 +75,7 @@ def dispatch_ranks_pallas(
     num_dests: int,
     *,
     block_tokens: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ):
     (t,) = dest.shape
     block_tokens = min(block_tokens, max(t, 1))
@@ -101,7 +99,7 @@ def dispatch_ranks_pallas(
             jax.ShapeDtypeStruct((1, num_dests), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, num_dests), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -13,7 +13,7 @@ from repro.kernels.moe_dispatch.moe_dispatch import dispatch_ranks_pallas
 
 def dispatch_ranks(dest: jax.Array, num_dests: int):
     """Stable in-bucket rank per token + per-destination counts."""
-    return dispatch_ranks_pallas(dest, num_dests, interpret=_k.INTERPRET)
+    return dispatch_ranks_pallas(dest, num_dests, interpret=_k.interpret())
 
 
 def dispatch_to_buckets(values: jax.Array, dest: jax.Array, num_dests: int,

@@ -13,5 +13,5 @@ def sketch_hist(ids: jax.Array, weights: jax.Array, multipliers: jax.Array,
     """Weighted count-min counters (depth, width) of integer ids."""
     return sketch_hist_pallas(
         ids.reshape(-1), weights.reshape(-1), multipliers, width,
-        interpret=_k.INTERPRET,
+        interpret=_k.interpret(),
     )

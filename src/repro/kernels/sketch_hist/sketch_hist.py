@@ -20,12 +20,14 @@ Same one-hot compare + reduction formulation as the histogram kernel
   "parallel"; the token-block axis is innermost and "arbitrary" so
   accumulation across token blocks sequentially revisits one output
   tile (zeroed on the first visit).
-* Each program hashes its token slab with its row's multiplier (uint32
-  wraparound multiply + logical shift — the VPU does both), builds
-  ``onehot[t, b] = (h_r(ids[t]) == bin0 + b)`` and reduces
-  ``sum_t onehot * w[t]`` into its ``(1, block_bins)`` output tile.
+* Ids and weights are ``(1, N)`` rows (tokens on lanes); the row's odd
+  multiplier is read from SMEM. Each program hashes its token slab in
+  int32 (wrapping multiply + logical shift — bit-identical to the
+  uint32 multiply-shift), builds ``onehot[b, t] = (h_r(ids[t]) == bin0 +
+  b)`` and accumulates ``w @ onehot^T`` — a ``(1, bt) x (bt, bins)`` MXU
+  matmul — into its ``(1, block_bins)`` output tile.
 
-Default blocks (1024 tokens × 1024 bins) keep the f32 one-hot at 4 MB —
+Default blocks (512 tokens × 1024 bins) keep the f32 one-hot at 2 MB —
 comfortably inside v5e VMEM next to the id/weight slabs.
 """
 
@@ -36,11 +38,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
-
-def _sketch_kernel(ids_ref, w_ref, mult_ref, out_ref, *,
+def _sketch_kernel(mult_ref, ids_ref, w_ref, out_ref, *,
                    block_bins: int, shift: int):
     tb = pl.program_id(2)  # token-block index (innermost, sequential)
 
@@ -48,18 +49,23 @@ def _sketch_kernel(ids_ref, w_ref, mult_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ids = ids_ref[...]   # (block_tokens,)
-    w = w_ref[...]       # (block_tokens,)
-    mult = mult_ref[0]   # this row's odd multiplier (uint32)
-    # Multiply-shift hash: uint32 multiply wraps mod 2^32, the logical
-    # right shift keeps the top log2(width) bits — h_r(x) in [0, width).
-    hashed = ((ids.astype(jnp.uint32) * mult) >> shift).astype(jnp.int32)
+    ids = ids_ref[...]                  # (1, block_tokens) int32
+    w = w_ref[...]                      # (1, block_tokens) f32
+    mult = mult_ref[pl.program_id(0)]   # this row's odd multiplier (SMEM)
+    # Multiply-shift hash: the int32 multiply wraps mod 2^32 exactly like
+    # a uint32 one, and the logical right shift keeps the top log2(width)
+    # bits — h_r(x) in [0, width).
+    hashed = jax.lax.shift_right_logical(ids * mult, shift)
     bin0 = pl.program_id(1) * block_bins
-    local = hashed[:, None] - bin0
-    onehot = (local == jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], block_bins), 1))
-    out_ref[...] += jnp.sum(
-        jnp.where(onehot, w[:, None], 0.0), axis=0)[None, :]
+    onehot = (
+        jax.lax.broadcasted_iota(jnp.int32, (block_bins, ids.shape[1]), 0)
+        + bin0 == hashed
+    ).astype(jnp.float32)               # (block_bins, block_tokens)
+    out_ref[...] += jax.lax.dot_general(
+        w, onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 @functools.partial(
@@ -72,22 +78,22 @@ def sketch_hist_pallas(
     multipliers: jax.Array,
     width: int,
     *,
-    block_tokens: int = 1024,
+    interpret: bool,
+    block_tokens: int = 512,
     block_bins: int = 1024,
-    interpret: bool = True,
 ) -> jax.Array:
     """``out[r, b] = sum_t weights[t] * (h_r(ids[t]) == b)``; (depth, width).
 
     ``width`` must be a power of two >= 2 (the hash is a top-bits
     extract); ``multipliers`` is the (depth,) uint32 vector of odd
-    hash multipliers.
+    hash multipliers. ``interpret`` selects the Pallas interpreter (CPU)
+    or Mosaic (TPU); the ``ops`` wrapper picks it from the backend.
     """
     (n,) = ids.shape
     (depth,) = multipliers.shape
     if width < 2 or width & (width - 1):
         raise ValueError(f"width must be a power of two >= 2, got {width}")
     shift = 32 - (width.bit_length() - 1)
-    block_tokens = min(block_tokens, max(n, 1))
     block_bins = min(block_bins, width)  # both powers of two: divides evenly
     # Pad tokens up to a block multiple; padded entries carry zero weight
     # (a padded id hashes to SOME bin, the weight keeps it from counting).
@@ -97,19 +103,22 @@ def sketch_hist_pallas(
         weights = jnp.concatenate([weights, jnp.zeros((pad,), weights.dtype)])
 
     grid = (depth, width // block_bins, ids.shape[0] // block_tokens)
-    return pl.pallas_call(
+    # Rows live on a leading axis of their own: a (1, block_bins) tile of
+    # a (depth, width) array breaks the (8, 128) tiling rule for depth < 8.
+    out = pl.pallas_call(
         functools.partial(_sketch_kernel, block_bins=block_bins, shift=shift),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_tokens,), lambda r, b, t: (t,)),
-            pl.BlockSpec((block_tokens,), lambda r, b, t: (t,)),
-            pl.BlockSpec((1,), lambda r, b, t: (r,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, block_tokens), lambda r, b, t: (0, t)),
+            pl.BlockSpec((1, block_tokens), lambda r, b, t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((1, block_bins), lambda r, b, t: (r, b)),
-        out_shape=jax.ShapeDtypeStruct((depth, width), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        out_specs=pl.BlockSpec((None, 1, block_bins), lambda r, b, t: (r, 0, b)),
+        out_shape=jax.ShapeDtypeStruct((depth, 1, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(ids.astype(jnp.int32), weights.astype(jnp.float32),
-      multipliers.astype(jnp.uint32))
+    )(jax.lax.bitcast_convert_type(multipliers.astype(jnp.uint32), jnp.int32),
+      ids.astype(jnp.int32)[None, :], weights.astype(jnp.float32)[None, :])
+    return out.reshape(depth, width)

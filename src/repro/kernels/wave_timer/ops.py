@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import io_callback
 
-from repro import kernels as _k
 from repro.analysis import allowlist as _allowlist
 from repro.kernels.wave_timer import calibration as _cal
 from repro.kernels.wave_timer import ref as wt_ref
@@ -72,9 +71,10 @@ def backend() -> str:
     """Resolve the tick backend: ``"device"`` | ``"callback"`` | ``"none"``."""
     if _FORCED is not None:
         return _FORCED
-    if not _k.INTERPRET and _wt.device_tick_primitive() is not None:
+    platform = jax.default_backend()
+    if platform == "tpu" and _wt.device_tick_primitive() is not None:
         return "device"
-    if jax.default_backend() == "cpu":
+    if platform == "cpu":
         return "callback"
     return "none"
 

@@ -120,7 +120,7 @@ def _tick_kernel_host(anchor_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def read_ticks_pallas(anchor, *, interpret: bool = True) -> jax.Array:
+def read_ticks_pallas(anchor, *, interpret: bool) -> jax.Array:
     """One tick stamp as ``(2,)`` uint32 (lo, hi) words.
 
     ``anchor`` is any scalar/array whose *computation* must precede the
@@ -164,7 +164,7 @@ def _stamp_through_kernel_host(primary_ref, *rest):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def stamp_through_pallas(primary, *anchors, interpret: bool = True):
+def stamp_through_pallas(primary, *anchors, interpret: bool):
     """Copy ``primary`` bit-identically and stamp the clock in one kernel.
 
     Returns ``(primary_copy, ticks)``. ``anchors`` are additional inputs

@@ -327,6 +327,9 @@ def main():
                          "from a sketch of the first FRAC of each shard's "
                          "pairs, refine the tail waves when the rest lands")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     if args.steady_state > 0:
         if args.scheduler is None:

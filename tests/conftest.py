@@ -1,125 +1,16 @@
-"""Shared fixtures + offline-collection shims.
+"""Shared fixtures.
 
-Two things live here besides fixtures:
-
-* A **hypothesis shim**: the property-test modules import
-  ``from hypothesis import given, settings, strategies as st`` at module
-  scope, which used to make the whole suite fail collection on machines
-  without the package. When hypothesis is absent we install a minimal
-  stand-in into ``sys.modules`` *before* test modules are imported
-  (conftest runs first), degrading every property test to a small sweep of
-  fixed-seed examples. With hypothesis installed, the real package wins.
-* No XLA_FLAGS device-count override — smoke tests and benches run on the
-  single real CPU device; CI sets
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the
-  shard_map tests exercise 8 virtual devices (see ``mesh8``).
+No XLA_FLAGS device-count override lives here — smoke tests and benches
+run on the single real CPU device; CI sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the shard_map
+tests exercise 8 virtual devices (see ``mesh8``).
 """
 
-import importlib.util
-import inspect
-import sys
-import types
-
+import jax
 import numpy as np
 import pytest
 
-
-# ---------------------------------------------------------------------------
-# Hypothesis shim (fixed-seed example mode when the package is missing).
-# ---------------------------------------------------------------------------
-
-
-def _install_hypothesis_shim() -> None:
-    if importlib.util.find_spec("hypothesis") is not None:
-        return  # real hypothesis available — use it
-
-    class _Strategy:
-        """A draw(rng) closure; just enough surface for this repo's tests."""
-
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(min_value, max_value):
-        return _Strategy(lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-    def floats(min_value=0.0, max_value=1.0, allow_nan=False,
-               allow_infinity=False, **_kw):
-        lo, hi = float(min_value), float(max_value)
-        return _Strategy(lambda rng: float(lo + (hi - lo) * rng.random()))
-
-    def lists(elements, min_size=0, max_size=10):
-        def draw(rng):
-            size = int(rng.integers(min_size, max_size + 1))
-            return [elements.draw(rng) for _ in range(size)]
-
-        return _Strategy(draw)
-
-    def sampled_from(options):
-        options = list(options)
-        return _Strategy(lambda rng: options[int(rng.integers(len(options)))])
-
-    def booleans():
-        return _Strategy(lambda rng: bool(rng.integers(2)))
-
-    _EXAMPLES = 12  # fixed-seed sweeps per property test in degraded mode
-
-    def given(*strategies):
-        """Drawn values fill the *rightmost* parameters (hypothesis rule);
-        leading parameters (``self``, pytest fixtures) pass through. The
-        wrapper's ``__signature__`` hides the drawn parameters so pytest
-        does not look for fixtures of those names."""
-
-        def decorate(fn):
-            sig = inspect.signature(fn)
-            params = list(sig.parameters.values())
-            kept = params[: len(params) - len(strategies)]
-
-            def wrapper(*args, **kwargs):
-                budget = getattr(fn, "_shim_max_examples", _EXAMPLES)
-                for seed in range(min(budget, _EXAMPLES)):
-                    rng = np.random.default_rng(seed)
-                    drawn = tuple(s.draw(rng) for s in strategies)
-                    fn(*args, *drawn, **kwargs)
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            wrapper.__signature__ = sig.replace(parameters=kept)
-            wrapper.hypothesis_shim = True
-            return wrapper
-
-        return decorate
-
-    def settings(max_examples=None, deadline=None, **_kw):
-        def decorate(fn):
-            if max_examples is not None:
-                # @settings sits under @given here, so it tags the original
-                # fn, which @given's wrapper reads at call time.
-                fn._shim_max_examples = int(max_examples)
-            return fn
-
-        return decorate
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    mod.assume = lambda condition: None
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.integers = integers
-    strategies.floats = floats
-    strategies.lists = lists
-    strategies.sampled_from = sampled_from
-    strategies.booleans = booleans
-    mod.strategies = strategies
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-_install_hypothesis_shim()
-
-import jax  # noqa: E402  (after the shim: jax import is slow, order is free)
-
-from repro import compat  # noqa: E402
+from repro import compat
 
 
 @pytest.fixture(scope="session")

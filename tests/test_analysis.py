@@ -135,7 +135,7 @@ def test_self_test_harness_roll_up():
 
 class TestEqnGraph:
     def test_sorts_found_inside_pjit(self):
-        """jnp.argsort lowers into a pjit sub-jaxpr; the flattened graph
+        """jnp.argsort lowers into a jit sub-jaxpr; the flattened graph
         must still expose the sort equation (and its stability flag)."""
         import jax.numpy as jnp
 
@@ -147,7 +147,17 @@ class TestEqnGraph:
         g = jg.EqnGraph(closed)
         sorts = g.by_prim("sort")
         assert sorts and sorts[0].eqn.params["is_stable"] is True
-        assert not any(n.prim == "pjit" for n in g.nodes)
+        assert not any(n.prim in jg._INLINE_PRIMS for n in g.nodes)
+
+    def test_nested_call_primitive_is_inlined(self):
+        """Pins the installed jax's nested-call primitive name: if jax
+        renames it again, the graph would silently stop seeing sorts."""
+        import jax.numpy as jnp
+
+        closed = jax.make_jaxpr(jnp.argsort)(jnp.arange(4))
+        (eqn,) = closed.jaxpr.eqns
+        assert eqn.primitive.name == "jit"
+        assert eqn.primitive.name in jg._INLINE_PRIMS
 
     def test_path_evidence_is_readable(self):
         import jax.numpy as jnp

@@ -284,7 +284,8 @@ class TestMeasuredMesh:
         whose timed waves traced/compiled (compilation is not a speed
         signal)."""
         mesh = _mesh(self.m)
-        with wt_ops.force_backend("none"):
+        with wt_ops.force_backend("none"), \
+                pytest.warns(RuntimeWarning, match="host-fenced executor"):
             job = _measured_job(self.m, mesh)
             job.run(_batch(0, self.m))
             # batch 0 traced/compiled its wave programs -> measured, invalid

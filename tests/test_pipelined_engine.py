@@ -1,11 +1,11 @@
-"""The chunked double-buffered shuffle→reduce engine + fused kernel.
+"""The chunked double-buffered shuffle→reduce engine + chunk reduce kernel.
 
 Covers the PR's acceptance surface:
 * pipelined phase B == sequential phase B **bit-exactly** on fixed seeds
   (integer-valued f32 inputs make every summation order exact);
 * ``plan_chunks`` invariants — every operation exactly once, chunk walk in
   increasing-load order, chunk count bounds;
-* the fused gather+segment-reduce kernel vs its jnp oracle across dtypes;
+* the gather + segment-reduce kernel path vs its jnp oracle across dtypes;
 * the ``auto`` strategy: picks a candidate, never balances worse than hash,
   and reports per-candidate cost estimates.
 """
@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from repro.core import pipeline as pipe
 from repro.core import simulator as sim
 from repro.core.mapreduce import MapReduceConfig, MapReduceJob
-from repro.kernels.fused_shuffle_reduce.ops import fused_shuffle_reduce
-from repro.kernels.fused_shuffle_reduce.ref import fused_gather_segment_reduce_ref
+from repro.kernels.segment_reduce.ops import segment_reduce_sorted
+from repro.kernels.segment_reduce.ref import gather_segment_reduce_ref
 from repro.kernels.moe_dispatch.ops import (dispatch_to_buckets,
                                             dispatch_to_buckets_chunked,
                                             plan_capacity_slabs)
@@ -62,7 +62,7 @@ def test_pipelined_bit_identical_to_sequential(rng, sched, chunks):
 
 
 def test_pipelined_bit_identical_with_kernels(rng):
-    """The fused-kernel path must agree bit-for-bit too (f32 accum both)."""
+    """The kernel path must agree bit-for-bit too (f32 accum both)."""
     m, K, V, n = 4, 128, 2, 16
     keys, vals, valid = _int_job_inputs(rng, m, K, V, 509)
     batch = (jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(valid))
@@ -168,7 +168,7 @@ def test_engine_chunk_walk_is_increasing_load_per_slot(rng):
 
 
 # ---------------------------------------------------------------------------
-# Fused kernel vs oracle
+# Gather + segment-reduce kernel vs oracle
 # ---------------------------------------------------------------------------
 
 
@@ -179,10 +179,8 @@ def test_fused_shuffle_reduce_dtype_sweep(rng, dtype, n, s, v):
     seg_unsorted = rng.integers(0, s, n).astype(np.int32)
     order = np.argsort(seg_unsorted, kind="stable").astype(np.int32)
     seg_sorted = jnp.asarray(seg_unsorted[order])
-    got = fused_shuffle_reduce(vals, jnp.asarray(order), seg_sorted, s,
-                               use_kernel=True)
-    ref = fused_gather_segment_reduce_ref(vals, jnp.asarray(order),
-                                          seg_sorted, s)
+    got = segment_reduce_sorted(vals[jnp.asarray(order)], seg_sorted, s)
+    ref = gather_segment_reduce_ref(vals, jnp.asarray(order), seg_sorted, s)
     atol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), atol=atol)
@@ -195,8 +193,8 @@ def test_fused_fallback_matches_kernel(rng):
     order = jnp.asarray(rng.permutation(n).astype(np.int32))
     # padding rows (seg == s) must be dropped by both paths
     seg[-5:] = s
-    a = fused_shuffle_reduce(vals, order, jnp.asarray(seg), s, use_kernel=True)
-    b = fused_shuffle_reduce(vals, order, jnp.asarray(seg), s, use_kernel=False)
+    a = segment_reduce_sorted(vals[order], jnp.asarray(seg), s)
+    b = gather_segment_reduce_ref(vals, order, jnp.asarray(seg), s)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 

@@ -97,6 +97,31 @@ def test_bss_approx_indices_valid(loads, target, eta):
     assert all(0 <= i < len(loads) for i in got)
 
 
+@given(st.lists(st.integers(0, 1000), min_size=1, max_size=12),
+       st.integers(1, 4000), st.floats(0.01, 0.5))
+@settings(max_examples=100, deadline=None)
+def test_bss_split_within_eta_of_best_subset(units, target, eta):
+    """The many-operation path keeps the FPTAS bound: eta * target."""
+    loads = [float(u) for u in units]
+    got = bss._bss_split(loads, float(target), eta)
+    assert len(set(got)) == len(got)
+    best = min(abs(sum(u for i, u in enumerate(units) if (mask >> i) & 1) - target)
+               for mask in range(1 << len(units)))
+    assert abs(sum(loads[i] for i in got) - target) <= best + eta * target + 1e-9
+
+
+def test_bss_at_real_cluster_count(rng):
+    """65,536 Zipf-loaded clusters on 8 slots: the one-grid DP would keep
+    ~500 GiB of snapshots; the split path plans in about a second."""
+    loads = np.bincount(rng.zipf(1.1, 1 << 20) % 65536, minlength=65536).astype(float)
+    target = loads.sum() / 8
+    assert len(loads) * len(loads) / 0.002 > bss.DP_BITS_BUDGET
+    chosen = bss.bss_approx(loads.tolist(), target, eta=0.002)
+    assert abs(loads[chosen].sum() - target) <= 0.002 * target
+    sched = S.schedule_bss(loads, 8)
+    assert sched.max_load <= S.schedule_lpt(loads, 8).max_load + 1e-6
+
+
 def test_lpt_assign_jax_matches_host():
     import jax.numpy as jnp
 

@@ -242,7 +242,8 @@ class TestMeasuredExecutorIntegration:
         ref = MapReduceJob(lambda s: s, MapReduceConfig(
             num_slots=self.m, num_clusters=24, scheduler="bss",
             pipeline_chunks=3), backend="vmap")
-        with wt_ops.force_backend("none"):
+        with wt_ops.force_backend("none"), \
+                pytest.warns(RuntimeWarning, match="host-fenced executor"):
             job = self._jobs(mesh)
             b = _batch(0, self.m)
             r, v = job.run(b), ref.run(b)
