@@ -64,10 +64,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
-from repro.core import clustering, pipeline as pipe
+from repro.core import clustering, pipeline as pipe, spans
 from repro.core import mesh_timing as mt
 from repro.core import schedule_cache as sc
 from repro.core import scheduler as sched_lib
@@ -216,6 +217,12 @@ class JobResult:
     quantize_exact: Optional[bool] = None  # quantized round-trip lossless? (None = off)
 
 
+def _pull(span: str, x) -> np.ndarray:
+    """``x`` copied to the host, inside the host span ``span`` with its bytes."""
+    with TraceAnnotation(span, bytes=x.nbytes):
+        return np.asarray(jax.device_get(x))
+
+
 # ---------------------------------------------------------------------------
 # Per-shard phase bodies (named-axis collectives; backend-agnostic).
 # ---------------------------------------------------------------------------
@@ -283,6 +290,7 @@ def _counting_sort_to_buckets(
     )
 
 
+@jax.named_scope(spans.SPILL)
 def _ragged_counting_sort_to_buckets(
     group: jnp.ndarray,      # (K,) int32 in [0, G] (G = invalid)
     values: jnp.ndarray,     # (K, V)
@@ -347,6 +355,7 @@ def _segment_reduce(cluster_ids, values, valid, num_clusters: int, reduce_op: st
     return out, counts
 
 
+@jax.named_scope(spans.COPY)
 def _copy_chunk(buckets, value_dim: int):
     """The "copy" phase of one chunk: all-to-all every bucket to its slot."""
     bv, bc, bm = buckets
@@ -356,6 +365,7 @@ def _copy_chunk(buckets, value_dim: int):
     return rv.reshape(-1, value_dim), rc.reshape(-1), rm.reshape(-1)
 
 
+@jax.named_scope(spans.REDUCE)
 def _reduce_chunk(
     rv, rc, rm,
     rank_of_cluster: jnp.ndarray,
@@ -395,6 +405,7 @@ def _reduce_chunk(
     return _segment_reduce(rc, rv, rm, num_clusters, reduce_op)
 
 
+@jax.named_scope(spans.REDUCE)
 def _sequential_reduce(
     rv, rc, rm,
     rank_of_cluster: jnp.ndarray,
@@ -577,6 +588,7 @@ def _phase_b_shard_coded(
         (jidx + 1)[:, None],
     ], axis=1)
 
+    @jax.named_scope(spans.COPY)
     def _a2a(x):
         return jax.lax.all_to_all(
             x, AXIS, split_axis=0, concat_axis=0, tiled=False
@@ -1082,6 +1094,8 @@ class MapReduceJob:
         # Trace telemetry: +1 every time a new executable is built. Steady-
         # state serving asserts this stays flat after warmup.
         self.jit_misses = 0
+        # Batches run so far: the ``batch`` counter of each os4m.batch span.
+        self.batches_run = 0
         # Schedule-reuse state (the ROADMAP serving item): holds the live
         # CachedSchedule snapshot + decision counters when cfg.reuse is set.
         # On shard_map the drift check is device-resident: the baseline
@@ -1614,24 +1628,28 @@ class MapReduceJob:
         jitted = self._jit_cache.get(cache_key) if cache_key is not None else None
         if jitted is not None:
             self._jit_cache.move_to_end(cache_key)
+            return jitted(*args)
+        self.jit_misses += 1
+        if self.backend == "vmap":
+            jitted = jax.jit(jax.vmap(
+                fn, in_axes=in_specs, out_axes=out_specs, axis_name=AXIS
+            ))
         else:
-            self.jit_misses += 1
-            if self.backend == "vmap":
-                jitted = jax.jit(jax.vmap(
-                    fn, in_axes=in_specs, out_axes=out_specs, axis_name=AXIS
-                ))
-            else:
-                jitted = jax.jit(compat.shard_map(
-                    fn,
-                    mesh=self.mesh,
-                    in_specs=self._to_pspec(in_specs),
-                    out_specs=self._to_pspec(out_specs),
-                ))
-            if cache_key is not None:
-                self._jit_cache[cache_key] = jitted
-                while len(self._jit_cache) > self._jit_cache_max:
-                    self._jit_cache.popitem(last=False)
-        return jitted(*args)
+            jitted = jax.jit(compat.shard_map(
+                fn,
+                mesh=self.mesh,
+                in_specs=self._to_pspec(in_specs),
+                out_specs=self._to_pspec(out_specs),
+            ))
+        if cache_key is not None:
+            self._jit_cache[cache_key] = jitted
+            while len(self._jit_cache) > self._jit_cache_max:
+                self._jit_cache.popitem(last=False)
+        # The first call traces, compiles (or loads from the persistent
+        # cache) and dispatches the new executable.
+        with TraceAnnotation(spans.JIT_BUILD,
+                             key=str(cache_key[0]) if cache_key else ""):
+            return jitted(*args)
 
     # -- measured shuffle-volume accounting ----------------------------------
 
@@ -1760,62 +1778,63 @@ class MapReduceJob:
         # strategy assigns by earliest finish time under the current
         # per-slot speed estimate (Q||C_max; None ≡ identical slots).
         strategy_costs = None
-        if assignment_override is not None:
-            # Prefix refinement: the assignment was committed by the
-            # wave-1 plan; only waves and capacities are recomputed.
-            strategy = strategy_override or cfg.scheduler
-            schedule = sched_lib.Schedule.from_assignment(
-                np.asarray(assignment_override, np.int32), key_dist, m,
-                speeds=speeds,
-            )
-        elif cfg.scheduler == "auto":
-            from repro.core import simulator as sim
-
-            strategy, schedule, strategy_costs = sim.pick_strategy(
-                key_dist, m, eta=cfg.eta,
-                pipelined=cfg.pipelined and pipeline_chunks > 1,
-                speeds=speeds,
-                # Measured wire rate (last batch) + per-slot locality: the
-                # model sees what the shuffle actually costs, so coding or
-                # quantizing the wire shifts strategy choice honestly.
-                bytes_per_pair=self._wire_rate(),
-                # The locality-aware wire model wants per-shard (m, n)
-                # counts; a sketch densifies its estimates only for this
-                # one auto-strategy path.
-                local_hist=(provider.to_dense(state) if dense_hist is None
-                            else dense_hist),
-            )
-        else:
-            strategy = cfg.scheduler
-            scheduler = sched_lib.get_scheduler(cfg.scheduler)
-            if cfg.scheduler == "hash":
-                schedule = scheduler(key_dist, m, keys=np.arange(n),
-                                     speeds=speeds)
-            elif dense_hist is None:
-                # Sketch plans schedule at *bin* granularity: the row-0
-                # cell sums are the exact total mass landing in each bin,
-                # so Q||C_max runs over ``width`` loads instead of ``n``
-                # and the scheduling cost is O(sketch), independent of the
-                # key count. The per-cluster assignment is a gather
-                # through the row-0 hash — clusters sharing a bin travel
-                # together, which is exactly the granularity the
-                # distinct-bin send bound already charges capacities for.
-                cells = state.reshape(m, provider.depth, provider.width)
-                bin_loads = np.asarray(cells[:, 0, :].sum(axis=0),
-                                       np.float64)
-                if cfg.scheduler in ("bss", "os4m"):
-                    bin_sched = scheduler(bin_loads, m, eta=cfg.eta,
-                                          speeds=speeds)
-                else:
-                    bin_sched = scheduler(bin_loads, m, speeds=speeds)
-                assignment = bin_sched.assignment[provider.bins()[0]]
+        with TraceAnnotation(spans.PLAN_ASSIGN):
+            if assignment_override is not None:
+                # Prefix refinement: the assignment was committed by the
+                # wave-1 plan; only waves and capacities are recomputed.
+                strategy = strategy_override or cfg.scheduler
                 schedule = sched_lib.Schedule.from_assignment(
-                    np.asarray(assignment, np.int32), key_dist, m,
-                    speeds=speeds)
-            elif cfg.scheduler in ("bss", "os4m"):
-                schedule = scheduler(key_dist, m, eta=cfg.eta, speeds=speeds)
+                    np.asarray(assignment_override, np.int32), key_dist, m,
+                    speeds=speeds,
+                )
+            elif cfg.scheduler == "auto":
+                from repro.core import simulator as sim
+
+                strategy, schedule, strategy_costs = sim.pick_strategy(
+                    key_dist, m, eta=cfg.eta,
+                    pipelined=cfg.pipelined and pipeline_chunks > 1,
+                    speeds=speeds,
+                    # Measured wire rate (last batch) + per-slot locality: the
+                    # model sees what the shuffle actually costs, so coding or
+                    # quantizing the wire shifts strategy choice honestly.
+                    bytes_per_pair=self._wire_rate(),
+                    # The locality-aware wire model wants per-shard (m, n)
+                    # counts; a sketch densifies its estimates only for this
+                    # one auto-strategy path.
+                    local_hist=(provider.to_dense(state) if dense_hist is None
+                                else dense_hist),
+                )
             else:
-                schedule = scheduler(key_dist, m, speeds=speeds)
+                strategy = cfg.scheduler
+                scheduler = sched_lib.get_scheduler(cfg.scheduler)
+                if cfg.scheduler == "hash":
+                    schedule = scheduler(key_dist, m, keys=np.arange(n),
+                                         speeds=speeds)
+                elif dense_hist is None:
+                    # Sketch plans schedule at *bin* granularity: the row-0
+                    # cell sums are the exact total mass landing in each bin,
+                    # so Q||C_max runs over ``width`` loads instead of ``n``
+                    # and the scheduling cost is O(sketch), independent of the
+                    # key count. The per-cluster assignment is a gather
+                    # through the row-0 hash — clusters sharing a bin travel
+                    # together, which is exactly the granularity the
+                    # distinct-bin send bound already charges capacities for.
+                    cells = state.reshape(m, provider.depth, provider.width)
+                    bin_loads = np.asarray(cells[:, 0, :].sum(axis=0),
+                                           np.float64)
+                    if cfg.scheduler in ("bss", "os4m"):
+                        bin_sched = scheduler(bin_loads, m, eta=cfg.eta,
+                                              speeds=speeds)
+                    else:
+                        bin_sched = scheduler(bin_loads, m, speeds=speeds)
+                    assignment = bin_sched.assignment[provider.bins()[0]]
+                    schedule = sched_lib.Schedule.from_assignment(
+                        np.asarray(assignment, np.int32), key_dist, m,
+                        speeds=speeds)
+                elif cfg.scheduler in ("bss", "os4m"):
+                    schedule = scheduler(key_dist, m, eta=cfg.eta, speeds=speeds)
+                else:
+                    schedule = scheduler(key_dist, m, speeds=speeds)
 
         # Static capacity for the all-to-all: the per-(shard,dest) worst
         # case from the per-shard statistics — shard i sends dest d exactly
@@ -2266,18 +2285,21 @@ class MapReduceJob:
         """
         key_hashes, values, valid = intermediate
         fn = self._jit_cache.get(("mask",))
-        if fn is None:
-            self.jit_misses += 1
-            n = self.cfg.num_clusters
+        if fn is not None:
+            return (key_hashes, values, fn(key_hashes, valid,
+                                           jnp.asarray(completed)))
+        self.jit_misses += 1
+        n = self.cfg.num_clusters
 
-            def mask(kh, valid, done):
-                """valid &= cluster not yet checkpointed."""
-                return valid & ~done[jnp.abs(kh) % n]
+        def mask(kh, valid, done):
+            """valid &= cluster not yet checkpointed."""
+            return valid & ~done[jnp.abs(kh) % n]
 
-            fn = jax.jit(mask)
-            self._jit_cache[("mask",)] = fn
-        return (key_hashes, values, fn(key_hashes, valid,
-                                       jnp.asarray(completed)))
+        fn = jax.jit(mask)
+        self._jit_cache[("mask",)] = fn
+        with TraceAnnotation(spans.JIT_BUILD, key="mask"):
+            return (key_hashes, values, fn(key_hashes, valid,
+                                           jnp.asarray(completed)))
 
     def _execute_checkpointed(self, intermediate, planned: sc.CachedSchedule,
                               local_k, k_per_shard: int):
@@ -2469,6 +2491,53 @@ class MapReduceJob:
         self.last_replayed_waves = replayed
         return vals, cnts, overflow_total
 
+    def _decide(self, cache: sc.ScheduleCache, local_k):
+        """Reuse or replan this batch: the drift check, then the cost gate.
+
+        Returns ``(decision, benefit, local_hist)``; the last two are set
+        only when the cost gate ran (it pulls the statistics to the host).
+        """
+        cfg = self.cfg
+        provider = self._stats
+        benefit = local_hist = None
+        decision = cache.decide(local_k, fresh_speeds=self.current_speeds())
+        if (decision.action == "replan" and decision.reason == "drift"
+                and cache.policy.cost_gate and cfg.scheduler == "auto"):
+            # The distribution drifted — but is a fresh plan actually
+            # better than the stale schedule's expected imbalance, net
+            # of the scheduler's own cost? (simulator cost model)
+            from repro.core import simulator as sim
+
+            local_hist = _pull(spans.STATS_PULL, local_k)
+            # The cost model wants dense per-shard counts; under a
+            # sketch these are the overestimate-only densifications.
+            benefit = sim.estimate_replan_benefit(
+                provider.key_dist(local_hist), cache.snapshot.schedule,
+                eta=cfg.eta,
+                pipelined=cfg.pipelined and cfg.pipeline_chunks > 1,
+                speeds=self.current_speeds(),
+                # Gate on MEASURED shuffle cost: the wire rate of the
+                # last accounted batch and the per-slot locality both
+                # shrink the copy term the model weighs replanning by.
+                bytes_per_pair=self._wire_rate(),
+                local_hist=provider.to_dense(local_hist),
+            )
+            if benefit["benefit"] <= 0.0:
+                # Not worth it: keep the plan, re-anchor the drift
+                # baseline so the question isn't re-asked every batch.
+                cache.snapshot.refresh_baseline(
+                    local_hist, key_dist=provider.key_dist(local_hist))
+                decision = sc.ReuseDecision(
+                    "reuse", "cost_gate", decision.drift,
+                    speed_drift=decision.speed_drift,
+                )
+        return decision, benefit, local_hist
+
+    def _plan_span(self, key_dist, k_per_shard: int) -> TraceAnnotation:
+        """The ``os4m.plan`` span, with the valid and the input pairs."""
+        return TraceAnnotation(spans.PLAN, valid_pairs=int(np.sum(key_dist)),
+                               input_pairs=self.cfg.num_slots * int(k_per_shard))
+
     # -- public API ----------------------------------------------------------
 
     def run(self, inputs) -> JobResult:
@@ -2479,7 +2548,16 @@ class MapReduceJob:
         histograms feed an on-device drift check first; a reused batch
         skips the statistics pull and the scheduler entirely and replays
         the cached plan, which by construction hits the phase-B jit cache.
+
+        Each step records a host span for a profiler trace
+        (:mod:`repro.core.spans`), all inside one ``os4m.batch``.
         """
+        self.batches_run += 1
+        with TraceAnnotation(spans.BATCH, batch=self.batches_run - 1):
+            return self._run_batch(inputs)
+
+    def _run_batch(self, inputs) -> JobResult:
+        """The body of :meth:`run`, one batch."""
         cfg = self.cfg
         m, n = cfg.num_slots, cfg.num_clusters
 
@@ -2507,41 +2585,10 @@ class MapReduceJob:
         cache = self.schedule_cache
 
         # ---- Reuse decision (on-device drift; only a scalar reaches host).
-        decision = None
-        benefit = None
-        local_hist = None
+        decision = benefit = local_hist = None
         if cache is not None:
-            decision = cache.decide(local_k, fresh_speeds=self.current_speeds())
-            if (decision.action == "replan" and decision.reason == "drift"
-                    and cache.policy.cost_gate and cfg.scheduler == "auto"):
-                # The distribution drifted — but is a fresh plan actually
-                # better than the stale schedule's expected imbalance, net
-                # of the scheduler's own cost? (simulator cost model)
-                from repro.core import simulator as sim
-
-                local_hist = np.asarray(jax.device_get(local_k))
-                # The cost model wants dense per-shard counts; under a
-                # sketch these are the overestimate-only densifications.
-                benefit = sim.estimate_replan_benefit(
-                    provider.key_dist(local_hist), cache.snapshot.schedule,
-                    eta=cfg.eta,
-                    pipelined=cfg.pipelined and cfg.pipeline_chunks > 1,
-                    speeds=self.current_speeds(),
-                    # Gate on MEASURED shuffle cost: the wire rate of the
-                    # last accounted batch and the per-slot locality both
-                    # shrink the copy term the model weighs replanning by.
-                    bytes_per_pair=self._wire_rate(),
-                    local_hist=provider.to_dense(local_hist),
-                )
-                if benefit["benefit"] <= 0.0:
-                    # Not worth it: keep the plan, re-anchor the drift
-                    # baseline so the question isn't re-asked every batch.
-                    cache.snapshot.refresh_baseline(
-                        local_hist, key_dist=provider.key_dist(local_hist))
-                    decision = sc.ReuseDecision(
-                        "reuse", "cost_gate", decision.drift,
-                        speed_drift=decision.speed_drift,
-                    )
+            with TraceAnnotation(spans.DECIDE):
+                decision, benefit, local_hist = self._decide(cache, local_k)
 
         # ---- Host plan (cold / drift / max_age) or cached replay.
         if decision is not None and decision.action == "reuse":
@@ -2553,19 +2600,20 @@ class MapReduceJob:
             # counters into the (n,) overestimate.
             key_dist = provider.key_dist(
                 local_hist if local_hist is not None
-                else np.asarray(jax.device_get(jnp.sum(local_k, axis=0))))
+                else _pull(spans.STATS_PULL, jnp.sum(local_k, axis=0)))
         else:
-            local_hist = np.asarray(jax.device_get(local_k))
+            local_hist = _pull(spans.STATS_PULL, local_k)
             key_dist = provider.key_dist(local_hist)
             prev = cache.snapshot if cache is not None else None
             if prefix_k is not None:
-                planned = self._plan_prefixed(
-                    local_hist, np.asarray(jax.device_get(prefix_k)),
-                    k_per_shard, prev=prev,
-                )
+                prefix_hist = _pull(spans.STATS_PULL, prefix_k)
+                with self._plan_span(key_dist, k_per_shard):
+                    planned = self._plan_prefixed(
+                        local_hist, prefix_hist, k_per_shard, prev=prev)
             else:
-                planned = self._plan(local_hist, key_dist, k_per_shard,
-                                     prev=prev)
+                with self._plan_span(key_dist, k_per_shard):
+                    planned = self._plan(local_hist, key_dist, k_per_shard,
+                                         prev=prev)
             if cache is not None:
                 cache.store(planned)
 
@@ -2579,22 +2627,27 @@ class MapReduceJob:
         timings: Optional[mt.WaveTimings] = None
         values = counts_np = None
         wire_vec = None
+
+        def execute(planned):
+            """Phase B under ``planned``: device results and the overflow count."""
+            timings = None
+            with TraceAnnotation(spans.PHASE_B):
+                if measured:
+                    out, counts, overflow, wire_vec, timings = (
+                        self._execute_measured(intermediate, planned))
+                else:
+                    out, counts, overflow, wire_vec = self._execute(
+                        intermediate, planned)
+            overflow_total = int(_pull(spans.OUTPUT_PULL, overflow).reshape(-1)[0])
+            return out, counts, wire_vec, timings, overflow_total
+
         if checkpointing:
             self.last_replay_plan = None
-            values, counts_np, overflow_total = self._execute_checkpointed(
-                intermediate, planned, local_k, k_per_shard)
-        elif measured:
-            out, counts, overflow, wire_vec, timings = self._execute_measured(
-                intermediate, planned)
-            overflow_total = int(
-                np.asarray(jax.device_get(overflow)).reshape(-1)[0]
-            )
+            with TraceAnnotation(spans.PHASE_B):
+                values, counts_np, overflow_total = self._execute_checkpointed(
+                    intermediate, planned, local_k, k_per_shard)
         else:
-            out, counts, overflow, wire_vec = self._execute(
-                intermediate, planned)
-            overflow_total = int(
-                np.asarray(jax.device_get(overflow)).reshape(-1)[0]
-            )
+            out, counts, wire_vec, timings, overflow_total = execute(planned)
 
         # ---- Capacity fallback: a replayed plan's statistics-sized
         # buffers were too small for this batch (drift under the threshold
@@ -2606,30 +2659,22 @@ class MapReduceJob:
         # estimate-overflow again.
         if decision is not None and decision.action == "reuse" and overflow_total > 0:
             cache.capacity_fallbacks += 1
-            local_hist = np.asarray(jax.device_get(local_k))
+            local_hist = _pull(spans.STATS_PULL, local_k)
             key_dist = provider.key_dist(local_hist)
-            planned = self._plan(local_hist, key_dist, k_per_shard,
-                                 prev=cache.snapshot)
+            with self._plan_span(key_dist, k_per_shard):
+                planned = self._plan(local_hist, key_dist, k_per_shard,
+                                     prev=cache.snapshot)
             cache.store(planned)
             decision = sc.ReuseDecision("replan", "overflow", decision.drift,
                                         speed_drift=decision.speed_drift)
             if checkpointing:
                 # Mid-batch kills already fired during the first walk, so
                 # this re-execution is a clean checkpointed pass.
-                values, counts_np, overflow_total = self._execute_checkpointed(
-                    intermediate, planned, local_k, k_per_shard)
-            elif measured:
-                out, counts, overflow, wire_vec, timings = (
-                    self._execute_measured(intermediate, planned))
-                overflow_total = int(
-                    np.asarray(jax.device_get(overflow)).reshape(-1)[0]
-                )
+                with TraceAnnotation(spans.PHASE_B):
+                    values, counts_np, overflow_total = self._execute_checkpointed(
+                        intermediate, planned, local_k, k_per_shard)
             else:
-                out, counts, overflow, wire_vec = self._execute(
-                    intermediate, planned)
-                overflow_total = int(
-                    np.asarray(jax.device_get(overflow)).reshape(-1)[0]
-                )
+                out, counts, wire_vec, timings, overflow_total = execute(planned)
 
         # ---- Estimate-commitment fallback (streaming prefix): wave 1's
         # committed cap under-provisioned this batch. Not a replan — the
@@ -2638,80 +2683,77 @@ class MapReduceJob:
         # batch re-executes drop-free (see _escalate_caps).
         if planned.caps_estimated and overflow_total > 0:
             self.capacity_fallbacks += 1
-            planned = self._escalate_caps(planned)
+            with self._plan_span(key_dist, k_per_shard):
+                planned = self._escalate_caps(planned)
             if cache is not None:
                 cache.store(planned)
-            if measured:
-                out, counts, overflow, wire_vec, timings = (
-                    self._execute_measured(intermediate, planned))
-            else:
-                out, counts, overflow, wire_vec = self._execute(
-                    intermediate, planned)
-            overflow_total = int(
-                np.asarray(jax.device_get(overflow)).reshape(-1)[0]
-            )
+            out, counts, wire_vec, timings, overflow_total = execute(planned)
 
-        if cache is not None:
-            cache.record(decision)
-
-        # ---- Close the Q||C_max feedback loop: this batch's phase-B wave
-        # timings (measured per-device clocks on a shard_map mesh,
-        # synthetic on the single-device vmap backend) update the speed
-        # estimate the *next* plan will schedule under.
-        self.last_wave_timings = timings
-        if timings is not None:
-            self._observe_measured(timings, planned)
-        else:
-            self._observe_wave_timings(planned, key_dist)
-
-        # Each cluster is reduced on exactly one slot; merge = sum over
-        # slots (the checkpointed executor already merged wave-by-wave).
         if not checkpointing:
-            values = np.asarray(jax.device_get(out)).reshape(m, n, -1).sum(axis=0)
-            counts_np = np.asarray(
-                jax.device_get(counts)).reshape(m, n).sum(axis=0)
+            pulled = out.nbytes + counts.nbytes + (
+                wire_vec.nbytes if wire_vec is not None else 0)
+            with TraceAnnotation(spans.OUTPUT_PULL, bytes=pulled):
+                out, counts, wire_vec = jax.device_get((out, counts, wire_vec))
+        with TraceAnnotation(spans.MERGE):
+            if cache is not None:
+                cache.record(decision)
 
-        # ---- Measured shuffle volume: device row counters → bytes with
-        # static row sizes. Feeds the result AND the next plan's cost
-        # model (``_wire_rate``), so the simulator charges the copy phase
-        # what the wire actually cost, not the modeled 64 B/pair.
-        shuffle_bytes = shuffle_rows = shuffle_pairs = None
-        replication_bytes = 0
-        quantize_exact = None
-        if wire_vec is not None:
-            wv = np.asarray(
-                jax.device_get(wire_vec), np.float64).reshape(-1, 4)[0]
-            acct = self._wire_accounting(wv, intermediate[1])
-            shuffle_bytes = acct["shuffle_bytes"]
-            shuffle_rows = acct["shuffle_rows"]
-            shuffle_pairs = acct["shuffle_pairs"]
-            replication_bytes = acct["replication_bytes"]
-            if cfg.quantize_shuffle:
-                quantize_exact = acct["inexact"] == 0
-            self._last_wire = (shuffle_bytes, shuffle_pairs)
+            # ---- Close the Q||C_max feedback loop: this batch's phase-B wave
+            # timings (measured per-device clocks on a shard_map mesh,
+            # synthetic on the single-device vmap backend) update the speed
+            # estimate the *next* plan will schedule under.
+            self.last_wave_timings = timings
+            if timings is not None:
+                self._observe_measured(timings, planned)
+            else:
+                self._observe_wave_timings(planned, key_dist)
 
-        # One Map operation per shard (paper footnote 1: Map task == operation).
-        net = clustering.network_cost_bytes(
-            num_map_ops=m, num_clusters=n, num_tasktrackers=m, num_reduce_tasks=m
-        )
-        return JobResult(
-            values=values,
-            counts=counts_np,
-            schedule=planned.schedule,
-            key_distribution=key_dist,
-            overflow=overflow_total,
-            network_cost=net,
-            strategy=planned.strategy,
-            strategy_costs=planned.strategy_costs,
-            reused=bool(decision is not None and decision.action == "reuse"),
-            plan_reason=decision.reason if decision is not None else "",
-            drift=decision.drift if decision is not None else None,
-            replan_benefit=benefit,
-            slot_speeds=planned.schedule.slot_speeds,
-            speed_drift=(decision.speed_drift if decision is not None else None),
-            shuffle_bytes=shuffle_bytes,
-            shuffle_rows=shuffle_rows,
-            shuffle_pairs=shuffle_pairs,
-            replication_bytes=replication_bytes,
-            quantize_exact=quantize_exact,
-        )
+            # Each cluster is reduced on exactly one slot; merge = sum over
+            # slots (the checkpointed executor already merged wave-by-wave).
+            if not checkpointing:
+                values = np.asarray(out).reshape(m, n, -1).sum(axis=0)
+                counts_np = np.asarray(counts).reshape(m, n).sum(axis=0)
+
+            # ---- Measured shuffle volume: device row counters → bytes with
+            # static row sizes. Feeds the result AND the next plan's cost
+            # model (``_wire_rate``), so the simulator charges the copy phase
+            # what the wire actually cost, not the modeled 64 B/pair.
+            shuffle_bytes = shuffle_rows = shuffle_pairs = None
+            replication_bytes = 0
+            quantize_exact = None
+            if wire_vec is not None:
+                wv = np.asarray(wire_vec, np.float64).reshape(-1, 4)[0]
+                acct = self._wire_accounting(wv, intermediate[1])
+                shuffle_bytes = acct["shuffle_bytes"]
+                shuffle_rows = acct["shuffle_rows"]
+                shuffle_pairs = acct["shuffle_pairs"]
+                replication_bytes = acct["replication_bytes"]
+                if cfg.quantize_shuffle:
+                    quantize_exact = acct["inexact"] == 0
+                self._last_wire = (shuffle_bytes, shuffle_pairs)
+
+            # One Map operation per shard (paper footnote 1: Map task == operation).
+            net = clustering.network_cost_bytes(
+                num_map_ops=m, num_clusters=n, num_tasktrackers=m, num_reduce_tasks=m
+            )
+            return JobResult(
+                values=values,
+                counts=counts_np,
+                schedule=planned.schedule,
+                key_distribution=key_dist,
+                overflow=overflow_total,
+                network_cost=net,
+                strategy=planned.strategy,
+                strategy_costs=planned.strategy_costs,
+                reused=bool(decision is not None and decision.action == "reuse"),
+                plan_reason=decision.reason if decision is not None else "",
+                drift=decision.drift if decision is not None else None,
+                replan_benefit=benefit,
+                slot_speeds=planned.schedule.slot_speeds,
+                speed_drift=(decision.speed_drift if decision is not None else None),
+                shuffle_bytes=shuffle_bytes,
+                shuffle_rows=shuffle_rows,
+                shuffle_pairs=shuffle_pairs,
+                replication_bytes=replication_bytes,
+                quantize_exact=quantize_exact,
+            )
