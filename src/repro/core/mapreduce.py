@@ -313,7 +313,14 @@ def _ragged_counting_sort_to_buckets(
     order = jnp.argsort(group, stable=True)
     g_sorted = group[order]
     idx = jnp.arange(k)
-    pos = idx - jnp.searchsorted(g_sorted, g_sorted, side="left")
+    # A pair's rank in its group is its index less the group's start, and
+    # g_sorted takes only G + 1 values: G + 1 searches in one compare-and-
+    # count pass, where a search per pair loops over all K pairs.
+    starts = jnp.searchsorted(
+        g_sorted, jnp.arange(num_groups + 1, dtype=g_sorted.dtype),
+        side="left", method="compare_all",
+    )
+    pos = idx - starts[g_sorted]
     g_clip = jnp.clip(g_sorted, 0, num_groups - 1)
     cap_of = jnp.asarray(group_caps, jnp.int32)[g_clip]
     in_range = g_sorted < num_groups
