@@ -1,13 +1,23 @@
 """The comparison that decides ``correct``: program outputs against the plain reference.
 
-Three numbers are compared, each against its own limit from the
-configuration's ``limits``:
+Both sides are tables of rows named by key, ``(keys, values, counts)``
+(:func:`rows`). A job kind's ``reference`` (and ``control``) returns
+either that triple or the dense pair ``(values, counts)``, row ``i`` being
+key ``i``; the program's output is read the same way, from the job kind's
+``outputs(result, num_groups)`` where it defines one, else from the
+``JobResult``'s dense ``values`` and ``counts`` (:func:`program_out`).
+:func:`compare` aligns two tables on the union of their keys, a row
+missing on one side reading as zero, and computes three numbers, each
+compared with its own limit from the configuration's ``limits``:
 
 - ``value_rel_err``: the widest gap of an output value from the float64
-  reference, over every group of every checked batch, as a share of the
-  reference value (of 1 where the reference is 0, an empty group);
-- ``count_mismatch``: groups whose pair count differs from the reference
-  (counts are exact in float32 below 2^24, so the limit is 0);
+  reference, over every key of every checked batch, as a share of the
+  reference value (of 1 where the reference is 0 or has no row);
+- ``count_mismatch``: keys whose pair count differs from the reference
+  (counts are exact in float32 below 2^24, so the limit is 0). A key on
+  one side only counts, and so does a key named by two rows of one side:
+  two keys merged into one row, or one key split over two, is a wrong
+  answer;
 - ``overflow``: pairs the engine reports as dropped (limit 0).
 
 A batch whose numbers pass a limit counts as failed. A value that is not
@@ -24,17 +34,56 @@ import numpy as np
 NAMES = ("value_rel_err", "count_mismatch", "overflow")
 
 
-def compare_batch(values, counts, overflow, ref_values, ref_counts) -> Dict[str, float]:
-    """The compared numbers of one batch."""
-    values = np.asarray(values, np.float64).reshape(ref_values.shape)
-    counts = np.asarray(counts, np.float64).reshape(ref_counts.shape)
-    gap = np.abs(values - ref_values) / np.maximum(np.abs(ref_values), 1.0)
+def rows(out):
+    """One side's output as rows named by key: a triple ``(keys, values,
+    counts)`` is that table; a pair ``(values, counts)`` is dense, row ``i``
+    being key ``i``."""
+    if len(out) == 3:
+        return tuple(out)
+    values, counts = out
+    return np.arange(np.shape(counts)[0]), values, counts
+
+
+def program_out(job_module, result, num_groups: int):
+    """What the program returned for one batch, as the job kind reads a
+    ``JobResult``: its ``outputs`` where it defines one, else the dense
+    ``(values, counts)``."""
+    outputs = getattr(job_module, "outputs", None)
+    if outputs is None:
+        return result.values, result.counts
+    return outputs(result, num_groups)
+
+
+def _on_union(keys, values, counts, union):
+    """One side's values and counts summed onto ``union``, the keys it names
+    twice or more, and the keys it has a row for."""
+    at = np.searchsorted(union, np.asarray(keys).reshape(-1))
+    v = np.zeros((union.size,) + values.shape[1:], np.float64)
+    c = np.zeros(union.size, np.float64)
+    np.add.at(v, at, values)
+    np.add.at(c, at, np.asarray(counts, np.float64).reshape(-1))
+    named = np.bincount(at, minlength=union.size)
+    return v, c, named > 1, named > 0
+
+
+def compare(table, ref_table, overflow) -> Dict[str, float]:
+    """The compared numbers of one batch: the program's ``table`` against the
+    reference's ``ref_table``, each ``(keys, values (R, V), counts (R,))``."""
+    keys, values, counts = table
+    ref_keys, ref_values, ref_counts = ref_table
+    ref_values = np.asarray(ref_values, np.float64)
+    values = np.asarray(values, np.float64).reshape((np.size(keys),) + ref_values.shape[1:])
+    union = np.union1d(np.asarray(keys).reshape(-1), np.asarray(ref_keys).reshape(-1))
+    v, c, dup, has = _on_union(keys, values, counts, union)
+    ref_v, ref_c, ref_dup, ref_has = _on_union(ref_keys, ref_values, ref_counts, union)
+    gap = np.abs(v - ref_v) / np.maximum(np.abs(ref_v), 1.0)
     rel = float(np.max(gap)) if gap.size else 0.0
     if not np.isfinite(rel):
         rel = float("inf")
+    wrong = (c != ref_c) | dup | ref_dup | (has != ref_has)
     return {
         "value_rel_err": rel,
-        "count_mismatch": float(np.count_nonzero(counts != ref_counts)),
+        "count_mismatch": float(np.count_nonzero(wrong)),
         "overflow": float(overflow),
     }
 

@@ -10,6 +10,9 @@ whatever implements it, it cannot beat either floor:
   another chip has to leave its chip. The fewest that must leave is, per
   group, its pairs less those on the chip that holds most of them; those
   bytes cross at the published per-chip ICI rate, all chips sending at once.
+  The count does not depend on how groups are labelled, so it is made over
+  the groups' dense ranks: a hashed 31-bit group id costs no more than a
+  small one.
 
 The floor is the larger of the two; ``bound`` names it.
 """
@@ -40,9 +43,11 @@ def phase_b_floor(groups: np.ndarray, valid: np.ndarray, *, num_shards: int,
     ici_s = 0.0
     if chips > 1:
         chip_of_shard = np.arange(num_shards) * chips // num_shards
-        flat = chip_of_shard[:, None] * num_groups + groups
-        per_chip = np.bincount(flat[valid], minlength=chips * num_groups)
-        per_chip = per_chip.reshape(chips, num_groups)
+        chip = np.broadcast_to(chip_of_shard[:, None], groups.shape)[valid]
+        present, rank = np.unique(groups[valid], return_inverse=True)
+        per_chip = np.bincount(chip * present.size + rank.reshape(-1),
+                               minlength=chips * present.size)
+        per_chip = per_chip.reshape(chips, present.size)
         leaving = int(per_chip.sum() - per_chip.max(axis=0).sum())
         ici_s = leaving * pair_bytes(value_dim) / (chips * peaks["ici_bytes_per_s"])
     floor = max(hbm_s, ici_s)

@@ -8,7 +8,9 @@ adding files and entries and edits nothing that exists:
     bench/configs/<config>.json     one deployment: source, job, engine settings,
                                     sizes, the limits of the correctness check
     bench/jobs/<job>.py             data generator, map function, plain reference
-                                    and lower-precision control of one job kind
+                                    and lower-precision control of one job kind;
+                                    ``outputs`` where its rows are named by key
+                                    (``bench/checks.py``)
     bench/traffic/<mix>.json        one traffic mix: ``kind`` and its parameters
     bench/traffic/<kind>.py         the generator that reads mixes of that kind
     bench/metrics/<metric>.py       ``read(run)`` for one per-layer metric

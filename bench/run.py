@@ -15,8 +15,8 @@ The cell, its configuration and its traffic mix are found by name through
    window runs under the profiler and per-layer metrics are read from the
    trace (``bench/metrics/<metric>.py``);
 3. after the window: peak device memory, the program's state freed, then
-   every batch of the window compared with the job's plain reference
-   (``bench/checks.py``).
+   every batch of the window compared with the job's plain reference,
+   row by row named by key (``bench/checks.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -189,7 +189,7 @@ def run_cell(args, cell=None, *, require_tpu: bool = True, peaks=None) -> dict:
                     wall = time.perf_counter() - t
                 records.append({"pool": p, "wall_s": wall, "reused": res.reused,
                                 "reason": res.plan_reason, "overflow": res.overflow,
-                                "values": res.values, "counts": res.counts})
+                                "result": res})
                 i += 1
             t_w1 = time.perf_counter()
     compile_events.armed = False
@@ -246,15 +246,16 @@ def run_cell(args, cell=None, *, require_tpu: bool = True, peaks=None) -> dict:
 
 
 def check_outputs(cell, job_module, host_pool, records) -> checks.Verdict:
-    """Every batch of the window against the plain reference of its rows."""
+    """Every batch of the window against the plain reference of its input,
+    row by row named by key."""
     n = int(cell.config["engine"]["num_clusters"])
     verdict = checks.Verdict(checks.limits_of(cell.config))
     refs = {}
     for r in records:
         if r["pool"] not in refs:
-            refs[r["pool"]] = job_module.reference(host_pool[r["pool"]], n)
-        verdict.add(checks.compare_batch(r["values"], r["counts"], r["overflow"],
-                                         *refs[r["pool"]]))
+            refs[r["pool"]] = checks.rows(job_module.reference(host_pool[r["pool"]], n))
+        out = checks.rows(checks.program_out(job_module, r["result"], n))
+        verdict.add(checks.compare(out, refs[r["pool"]], r["overflow"]))
     return verdict
 
 

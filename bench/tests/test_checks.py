@@ -69,6 +69,22 @@ def run_tiny(seed: int = 2**31 + 17, seconds: float = 0.5, chips: int = 1) -> di
                         peaks=layout.peaks("TPU v5 lite"))
 
 
+def compare_dense(values, counts, overflow, ref_values, ref_counts):
+    return checks.compare(checks.rows((values, counts)),
+                          checks.rows((ref_values, ref_counts)), overflow)
+
+
+def compare_by_position(values, counts, overflow, ref_values, ref_counts):
+    """The comparison as it was before rows were named by key: by position."""
+    values = np.asarray(values, np.float64).reshape(ref_values.shape)
+    counts = np.asarray(counts, np.float64).reshape(ref_counts.shape)
+    gap = np.abs(values - ref_values) / np.maximum(np.abs(ref_values), 1.0)
+    rel = float(np.max(gap)) if gap.size else 0.0
+    return {"value_rel_err": rel if np.isfinite(rel) else float("inf"),
+            "count_mismatch": float(np.count_nonzero(counts != ref_counts)),
+            "overflow": float(overflow)}
+
+
 def test_reference_matches_a_loop():
     cell = tiny_cell()
     job = layout.job_module(cell)
@@ -116,8 +132,8 @@ def test_control_is_rejected(chips):
     job = layout.job_module(cell)
     verdict = checks.Verdict(checks.limits_of(cell.config))
     for batch in host_pool(cell, 11):
-        verdict.add(checks.compare_batch(*job.control(batch, 16384), 0,
-                                         *job.reference(batch, 16384)))
+        verdict.add(compare_dense(*job.control(batch, 16384), 0,
+                                  *job.reference(batch, 16384)))
     assert not verdict.correct
     assert verdict.worst["value_rel_err"] > 3 * verdict.limits["value_rel_err"]
 
@@ -141,7 +157,9 @@ def test_one_wrong_output_is_caught(fault):
     else:
         overflow = 1
     verdict = checks.Verdict(checks.limits_of(cell.config))
-    assert not verdict.add(checks.compare_batch(values, counts, overflow, ref_v, ref_c))
+    numbers = compare_dense(values, counts, overflow, ref_v, ref_c)
+    assert numbers == compare_by_position(values, counts, overflow, ref_v, ref_c)
+    assert not verdict.add(numbers)
     assert verdict.failed == 1 and not verdict.correct
 
 
@@ -185,12 +203,14 @@ def _patch(monkeypatch, fault: str):
         monkeypatch.setattr(mr, "_reduce_chunk", reduce_chunk)
     elif fault == "stale_outputs":
         orig = mr.MapReduceJob.run
-        first = {}
+        last = {}
 
-        def run_once(self, inputs):
+        def run_stale(self, inputs):
             res = orig(self, inputs)
-            return first.setdefault(id(self), res)
-        monkeypatch.setattr(mr.MapReduceJob, "run", run_once)
+            stale = last.get(id(self), res)
+            last[id(self)] = res
+            return stale
+        monkeypatch.setattr(mr.MapReduceJob, "run", run_stale)
     else:
         raise ValueError(fault)
 
@@ -230,3 +250,113 @@ def test_four_chip_cell_on_virtual_devices(fault):
     result = json.loads(out.stdout.strip().splitlines()[-1])
     assert result["count"] == 4 and result["attempted"] >= 1
     assert result["correct"] == (fault == "")
+
+
+# ---------------------------------------------------------------------------
+# Rows named by key.
+# ---------------------------------------------------------------------------
+
+
+def _keyed_reference():
+    keys = np.array([3, 17, 40, 1 << 30, (1 << 31) - 1])
+    counts = np.array([5.0, 1.0, 7.0, 2.0, 9.0])
+    return keys, counts[:, None].copy(), counts
+
+
+@pytest.mark.parametrize("fault", ["merged", "dropped", "duplicated", "extra", "renamed"])
+def test_keyed_fault_fails_count_mismatch(fault):
+    keys, values, counts = _keyed_reference()
+    if fault == "merged":      # keys 17 and 40 summed into one row named 17
+        k, v, c = keys[[0, 1, 3, 4]], values[[0, 1, 3, 4]], counts[[0, 1, 3, 4]]
+        v[1] += values[2]
+        c[1] += counts[2]
+    elif fault == "dropped":
+        k, v, c = keys[1:], values[1:], counts[1:]
+    elif fault == "duplicated":  # one key split over two rows, the sums still right
+        k = np.append(keys, keys[2])
+        v = np.concatenate([values, [[0.0]]])
+        c = np.append(counts, 0.0)
+    elif fault == "extra":
+        k, v, c = np.append(keys, 99), np.concatenate([values, [[0.0]]]), np.append(counts, 0.0)
+    else:
+        k, v, c = keys.copy(), values, counts
+        k[0] = 4
+    numbers = checks.compare((k, v, c), (keys, values, counts), 0)
+    assert numbers["count_mismatch"] >= 1, numbers
+    verdict = checks.Verdict({"value_rel_err": 0.0, "count_mismatch": 0.0, "overflow": 0.0})
+    assert not verdict.add(numbers)
+
+
+def test_keyed_rows_in_any_order_read_as_their_dense_table():
+    rng = np.random.default_rng(3)
+    n = 64
+    ref_c = rng.integers(0, 5, n).astype(np.float64)
+    ref_v = (ref_c * 2.5)[:, None]
+    v, c = ref_v.astype(np.float32), ref_c.astype(np.float32)
+    v[7] *= 1.0 + 2e-6
+    c[9] += 1
+    dense = compare_dense(v, c, 0, ref_v, ref_c)
+    assert dense == compare_by_position(v, c, 0, ref_v, ref_c)
+    order, ref_order = rng.permutation(n), rng.permutation(n)
+    keyed = checks.compare((order, v[order], c[order]),
+                           (ref_order, ref_v[ref_order], ref_c[ref_order]), 0)
+    assert keyed == dense
+    assert dense["count_mismatch"] == 1 and dense["value_rel_err"] > 0
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_dense_check_numbers_are_those_by_position(chips):
+    """The Q15 cells are read densely: their check numbers stay bit for bit
+    what the comparison by position gave, on sound and on control outputs."""
+    cell = tiny_cell(chips=chips)
+    job = layout.job_module(cell)
+    for batch in host_pool(cell, 2**31 + 23):
+        ref = job.reference(batch, 16384)
+        for out in (ref, job.control(batch, 16384)):
+            values, counts = (np.asarray(a, np.float32) for a in out)
+            assert (compare_dense(values, counts, 0, *ref)
+                    == compare_by_position(values, counts, 0, *ref))
+
+
+def keyed(job):
+    """Q15's job kind with its rows named by key, as a kind whose engine
+    reduces per key would name them: the program's rows are the clusters
+    that reduced a pair, the reference's the suppliers with a pair."""
+    def outputs(result, num_groups):
+        keys = np.flatnonzero(result.counts)
+        return keys, result.values[keys], result.counts[keys]
+
+    def reference(batch, num_groups):
+        values, counts = job.reference(batch, num_groups)
+        keys = np.flatnonzero(counts)
+        return keys, values[keys], counts[keys]
+
+    kind = {k: getattr(job, k) for k in dir(job) if not k.startswith("_")}
+    return types.SimpleNamespace(**dict(kind, outputs=outputs, reference=reference))
+
+
+@pytest.mark.parametrize("fault", ["", "keys_merged", "half_batch_dropped"])
+def test_keyed_job_kind_through_a_whole_run(monkeypatch, fault):
+    """A job kind with ``outputs`` is read by key in a run: sound outputs
+    are correct, and suppliers merged in pairs by the fold into clusters
+    fail ``count_mismatch``."""
+    dense = layout.job_module
+    monkeypatch.setattr(layout, "job_module", lambda cell: keyed(dense(cell)))
+    if fault == "keys_merged":
+        from repro.core import mapreduce as mr
+
+        orig = mr._phase_a_shard
+
+        def phase_a(shard, map_fn, **kw):
+            def merged(x):
+                key, value, valid = map_fn(x)
+                return key - (key % 2), value, valid
+            return orig(shard, merged, **kw)
+        monkeypatch.setattr(mr, "_phase_a_shard", phase_a)
+    elif fault:
+        _patch(monkeypatch, fault)
+    result = run_tiny(seconds=1.0)
+    assert result["attempted"] >= 1
+    assert result["correct"] == (fault == ""), result["checks"]
+    if fault == "keys_merged":
+        assert result["checks"]["count_mismatch"]["value"] >= 10

@@ -63,6 +63,65 @@ def test_floors_one_and_four_chips():
     assert two["ici_s"] == pytest.approx(3 * 8 / (2 * 200e9))
 
 
+def _leaving_by_group_id(groups, valid, num_shards, num_groups, chips):
+    """The pairs that must leave their chip, counted over the job's group ids
+    as they come, one bin a (chip, id): how the floor was counted before it
+    took dense ranks."""
+    groups = np.asarray(groups).reshape(num_shards, -1)
+    chip_of_shard = np.arange(num_shards) * chips // num_shards
+    flat = chip_of_shard[:, None] * num_groups + groups
+    per_chip = np.bincount(flat[valid], minlength=chips * num_groups)
+    per_chip = per_chip.reshape(chips, num_groups)
+    return int(per_chip.sum() - per_chip.max(axis=0).sum())
+
+
+@pytest.mark.parametrize("cell_name", ["q15-skew.1chip", "q15-skew.4chip"])
+def test_q15_floors_are_those_by_group_id(cell_name):
+    """Dense ranks leave both Q15 cells' floors bit for bit as they were."""
+    import test_checks
+
+    cell = layout.load_cell(cell_name)
+    chips, slots = cell.chips, int(cell.config["engine"]["num_slots"])
+    tiny = test_checks.tiny_cell(cell_name, chips=chips)
+    job = layout.job_module(tiny)
+    peaks = layout.peaks("TPU v5 lite")
+    for batch in test_checks.host_pool(tiny, 2**31 + 61):
+        groups, valid = job.group_ids(batch), job.valid(batch)
+        got = floors.phase_b_floor(groups, valid, num_shards=slots, num_groups=16384,
+                                   value_dim=1, num_reducers=slots, chips=chips, peaks=peaks)
+        leaving = _leaving_by_group_id(groups, valid, slots, 16384, chips)
+        assert (leaving > 0) == (chips > 1)
+        ici_s = leaving * floors.pair_bytes(1) / (chips * peaks["ici_bytes_per_s"])
+        assert got["ici_s"] == ici_s
+        if chips > 1:
+            assert got["bound"] == ("ici" if ici_s > got["hbm_s"] else "hbm")
+
+
+def test_floors_take_hashed_31_bit_group_ids():
+    """Hashed keys: the ICI count allocates bins for the groups present, not
+    for every 31-bit id (4 chips x 2^31 bins would be 64 GiB)."""
+    import tracemalloc
+
+    rng = np.random.default_rng(8)
+    words = rng.integers(0, 1 << 31, 5000)
+    groups = words[rng.integers(0, 5000, (4, 1 << 14))]
+    valid = rng.random(groups.shape) < 0.9
+    tracemalloc.start()
+    try:
+        got = floors.phase_b_floor(groups, valid, num_shards=4, num_groups=5000, value_dim=1,
+                                   num_reducers=4, chips=4, peaks=layout.peaks("TPU v5 lite"))
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert peak < 16 << 20
+    per_group = {}
+    for chip in range(4):
+        for g in groups[chip][valid[chip]].tolist():
+            per_group.setdefault(g, [0] * 4)[chip] += 1
+    leaving = sum(sum(c) - max(c) for c in per_group.values())
+    assert got["ici_s"] == leaving * floors.pair_bytes(1) / (4 * 200e9)
+
+
 @pytest.fixture(scope="module", params=RECORDED_CELLS)
 def recorded(request):
     trace = tr.load(DATA / f"{request.param}.xplane.pb.gz")

@@ -48,8 +48,8 @@ def control_readings(cell, seed: int) -> dict:
     n = int(cfg["engine"]["num_clusters"])
     verdict = checks.Verdict(checks.limits_of(cfg))
     for batch in pool:
-        values, counts = job_module.control(batch, n)
-        verdict.add(checks.compare_batch(values, counts, 0, *job_module.reference(batch, n)))
+        verdict.add(checks.compare(checks.rows(job_module.control(batch, n)),
+                                   checks.rows(job_module.reference(batch, n)), 0))
     return {"batches": verdict.attempted, "passes": verdict.correct, "worst": verdict.worst}
 
 
