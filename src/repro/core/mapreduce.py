@@ -48,7 +48,14 @@ collectives:
 
 Data model: a Map operation emits up to ``K`` intermediate pairs
 ``(key_hash:int32, value:(V,)float32, valid:bool)``. Keys are pre-hashed by
-the user's map function (or by :func:`repro.data.text.hash_tokens`).
+the user's map function (text by :func:`repro.data.text.hash_tokens`).
+
+Two options follow Hadoop's job set-up. ``combine`` is its combiner: each
+map shard's pairs are reduced by their full key before the statistics, so
+OS4M balances, and phase B moves, the combined pairs. ``keyed_output`` is
+one Reduce call per key, the paper's operation: the wire carries each
+pair's key, each slot reduces by key, and the result is a
+``(keys, values, counts)`` table instead of one row per cluster.
 """
 
 from __future__ import annotations
@@ -185,14 +192,27 @@ class MapReduceConfig:
     # overflow then triggers the exact escape hatch (caps escalate to the
     # safe bound and the batch re-executes — outputs stay exact).
     stream_prefix: Optional[float] = None
+    # Hadoop's combiner (``Job.setCombinerClass``, here the job's own
+    # ``reduce_op``): after the map, an executable of its own (``combine``)
+    # reduces each shard's valid pairs by their full key and compacts them
+    # to a static per-shard capacity C; the statistics, the plan and phase B
+    # then see the combined pairs, each carrying its count of map pairs. C
+    # grows to cover the largest per-shard count seen, and a batch past it
+    # re-runs its combiner, so no pair is dropped (MapReduceJob._combine).
+    combine: bool = False
+    # One Reduce call per key (the paper's operation) instead of per
+    # operation cluster: the wire carries each pair's key, each slot
+    # reduces what it received by key, and JobResult holds a table of
+    # (keys, values, counts), one row per key.
+    keyed_output: bool = False
 
 
 @dataclasses.dataclass
 class JobResult:
     """Outputs + provenance of one ``run()`` (fresh plan or cached replay)."""
 
-    values: np.ndarray          # (num_clusters, V) reduced outputs
-    counts: np.ndarray          # (num_clusters,) pairs per cluster
+    values: np.ndarray          # (num_clusters, V) reduced outputs; (R, V) keyed
+    counts: np.ndarray          # (num_clusters,) pairs per cluster; (R,) keyed
     schedule: sched_lib.Schedule
     key_distribution: np.ndarray  # K = (k_1..k_n) (cluster loads, §4.1)
     overflow: int               # pairs dropped by capacity clamp (0 in normal runs)
@@ -215,6 +235,9 @@ class JobResult:
     shuffle_pairs: Optional[int] = None   # non-local pairs the wire carried
     replication_bytes: int = 0            # coded replica-exchange bytes (not shuffle)
     quantize_exact: Optional[bool] = None  # quantized round-trip lossless? (None = off)
+    # keyed_output: the R keys that had a pair, one row of values and
+    # counts each, in no particular order (None when rows are clusters).
+    keys: Optional[np.ndarray] = None
 
 
 def _pull(span: str, x) -> np.ndarray:
@@ -226,6 +249,12 @@ def _pull(span: str, x) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # Per-shard phase bodies (named-axis collectives; backend-agnostic).
 # ---------------------------------------------------------------------------
+
+
+def _map_shard(shard_input, map_fn: Callable):
+    """The job's Map on one shard: ``(key_hashes int32, values, valid)``."""
+    key_hashes, values, valid = map_fn(shard_input)
+    return key_hashes.astype(jnp.int32), values, valid
 
 
 def _phase_a_shard(
@@ -249,8 +278,7 @@ def _phase_a_shard(
     return ``concat([full_state, prefix_state])``, so wave-1 planning
     can start from the prefix while the tail is conceptually in flight.
     """
-    key_hashes, values, valid = map_fn(shard_input)
-    key_hashes = key_hashes.astype(jnp.int32)
+    key_hashes, values, valid = _map_shard(shard_input, map_fn)
     cluster_ids = jnp.abs(key_hashes) % num_clusters
     weights = valid.astype(jnp.float32)
     state = stats_fn(cluster_ids, weights)
@@ -261,6 +289,106 @@ def _phase_a_shard(
         prefix_state = stats_fn(cluster_ids, weights * in_prefix)
         state = jnp.concatenate([state, prefix_state])
     return (key_hashes, values, valid), state
+
+
+def _segmented_scan(segment, values, counts, reduce_op: str):
+    """Inclusive scan of ``values`` (``max`` or a sum) and ``counts`` (a sum)
+    within runs of equal ``segment``: log2(K) shifted steps (Hillis and
+    Steele), each an elementwise pass, so it compiles and runs as a few
+    fusions at any K."""
+    k, d = segment.shape[0], 1
+    while d < k:
+        same = jnp.concatenate([jnp.zeros((d,), bool), segment[d:] == segment[:-d]])
+        prev = jnp.concatenate([jnp.zeros((d,) + values.shape[1:], values.dtype),
+                                values[:-d]])
+        folded = jnp.maximum(prev, values) if reduce_op == "max" else prev + values
+        values = jnp.where(same[:, None], folded, values)
+        counts = jnp.where(same, counts + jnp.concatenate(
+            [jnp.zeros((d,), counts.dtype), counts[:-d]]), counts)
+        d *= 2
+    return values, counts
+
+
+def _runs_by_key(keys, values, valid, reduce_op: str, counted: bool):
+    """Pairs sorted by key, each run of one key reduced into its last pair.
+
+    One sort on a single int32 key carries the value columns and the
+    counts (no gather); a segmented scan then folds each run with
+    ``reduce_op``. Invalid pairs take the largest key and the fold's
+    identity, with count 0, so where a valid key is that largest one they
+    share its run and add nothing. ``counted`` says the last value column
+    holds each pair's count of map pairs (pairs a combiner made): counts
+    then sum that column and the other columns fold with ``max`` for
+    ``max``, else with a sum.
+    Returns ``(keys, values (K, V), counts (K,), last (K,))``, ``last``
+    marking the last pair of each key's run, where the run's result is.
+    """
+    if counted:
+        values, counts = values[:, :-1], values[:, -1].astype(jnp.float32)
+        reduce_op = "max" if reduce_op == "max" else "sum"
+    else:
+        counts = jnp.ones(keys.shape, jnp.float32)
+        if reduce_op == "count":
+            values = values[:, :0]
+    identity = jnp.finfo(values.dtype).min if reduce_op == "max" else 0
+    cols = [jnp.where(valid, values[:, j], identity) for j in range(values.shape[-1])]
+    # Unstable: the order within a key's run only orders a float fold (no
+    # receiver rebuilds this sort), and one key compiles much faster on a
+    # TPU than the two a stable sort amounts to.
+    keys, counts, *cols = jax.lax.sort(
+        (jnp.where(valid, keys, jnp.iinfo(jnp.int32).max),
+         jnp.where(valid, counts, 0.0), *cols), num_keys=1, is_stable=False)
+    values = jnp.stack(cols, axis=-1) if cols else values[:, :0]
+    differs = keys[1:] != keys[:-1]
+    segment = jnp.cumsum(jnp.concatenate([jnp.ones((1,), bool), differs]))
+    values, counts = _segmented_scan(segment, values, counts, reduce_op)
+    if not cols:
+        values = counts[:, None].astype(values.dtype)
+    last = jnp.concatenate([differs, jnp.ones((1,), bool)]) & (counts > 0)
+    return keys, values, counts, last
+
+
+@jax.named_scope(spans.REDUCE)
+def _reduce_by_key(keys, values, valid, reduce_op: str, counted: bool):
+    """A slot's per-key Reduce of the pairs it received: a table with one
+    row per received pair, ``(keys, values, counts)``, whose rows with a
+    count are the keys' results (counts are zero elsewhere)."""
+    keys, values, counts, last = _runs_by_key(keys, values, valid, reduce_op,
+                                              counted)
+    return (keys, jnp.where(last[:, None], values, 0),
+            jnp.where(last, counts, 0.0))
+
+
+def _combine_shard(intermediate, capacity: int, num_clusters: int,
+                   stats_fn: Callable, reduce_op: str):
+    """Hadoop's combiner on one map shard, then its statistics.
+
+    The shard's valid pairs are reduced by their full key (``reduce_op``)
+    and the runs compacted, in key order, to ``capacity`` rows: each row
+    a key, its combined values and, as one more value column, its count of
+    map pairs. The ``(K,)`` run ends are counted once (a cumulative sum);
+    row ``r`` is the ``r``-th end, found by a binary search per row, so
+    only ``capacity`` rows are gathered. Returns the combined pairs, the
+    statistics over them (the loads phase B moves) and the shard's number
+    of keys; past ``capacity`` keys are left out and the caller re-runs.
+    """
+    keys, values, valid = intermediate
+    with jax.named_scope(spans.COMBINE):
+        keys, values, counts, last = _runs_by_key(keys, values, valid,
+                                                  reduce_op, counted=False)
+        ends = jnp.cumsum(last.astype(jnp.int32))
+        rows = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+        at = jnp.searchsorted(ends, rows, side="left")
+        kept = rows <= ends[-1]
+        at = jnp.minimum(at, keys.shape[0] - 1)
+        combined = (
+            jnp.where(kept, keys[at], 0),
+            jnp.concatenate([values[at], counts[at][:, None].astype(values.dtype)],
+                            axis=-1),
+            kept,
+        )
+    state = stats_fn(jnp.abs(combined[0]) % num_clusters, kept.astype(jnp.float32))
+    return combined, state, ends[-1:]
 
 
 def _counting_sort_to_buckets(
@@ -341,12 +469,22 @@ def _ragged_counting_sort_to_buckets(
     return bucket_values, bucket_clusters, bucket_valid, overflow
 
 
-def _segment_reduce(cluster_ids, values, valid, num_clusters: int, reduce_op: str):
-    """Reduce the "run" phase: aggregate pairs per cluster (jnp path)."""
+def _segment_reduce(cluster_ids, values, valid, num_clusters: int, reduce_op: str,
+                    counted: bool = False):
+    """Reduce the "run" phase: aggregate pairs per cluster (jnp path).
+
+    ``counted``: the last value column is each pair's count of map pairs
+    (combined pairs, see :func:`_runs_by_key`).
+    """
     w = valid.astype(values.dtype)[..., None]
     seg = jnp.where(valid, cluster_ids, num_clusters)
+    weights = valid.astype(jnp.float32)
+    if counted:
+        weights = weights * values[:, -1].astype(jnp.float32)
+        values = values[:, :-1]
+        reduce_op = "max" if reduce_op == "max" else "sum"
     counts = jax.ops.segment_sum(
-        valid.astype(jnp.float32), seg, num_segments=num_clusters + 1
+        weights, seg, num_segments=num_clusters + 1
     )[:-1]
     if reduce_op == "sum":
         out = jax.ops.segment_sum(values * w, seg, num_segments=num_clusters + 1)[:-1]
@@ -379,6 +517,7 @@ def _reduce_chunk(
     num_clusters: int,
     reduce_op: str,
     use_kernel: bool,
+    counted: bool = False,
 ):
     """The "sort" + "run" of one received chunk.
 
@@ -409,7 +548,7 @@ def _reduce_chunk(
             rm.astype(jnp.float32), seg, num_segments=num_clusters + 1
         )[:-1]
         return out, counts
-    return _segment_reduce(rc, rv, rm, num_clusters, reduce_op)
+    return _segment_reduce(rc, rv, rm, num_clusters, reduce_op, counted)
 
 
 @jax.named_scope(spans.REDUCE)
@@ -419,6 +558,7 @@ def _sequential_reduce(
     num_clusters: int,
     reduce_op: str,
     use_kernel: bool,
+    counted: bool = False,
 ):
     """Whole-input "sort"+"run" — Hadoop's Fig 4(a) Reduce on one shard.
 
@@ -439,7 +579,7 @@ def _sequential_reduce(
     # Identical-sort wire contract: stability explicit, never a default.
     order = jnp.argsort(rank, stable=True)
     return _segment_reduce(
-        rc[order], rv[order], rm[order], num_clusters, reduce_op
+        rc[order], rv[order], rm[order], num_clusters, reduce_op, counted
     )
 
 
@@ -800,6 +940,8 @@ def _phase_b_shard(
     chunk_of_cluster: jnp.ndarray,  # (n_clusters,) chunk id per cluster
     cfg_static: Tuple,
     stamp_through=None,
+    keyed: bool = False,
+    counted: bool = False,
 ):
     """Chunked shuffle ("copy") + pipelined reduce ("run") — §4.1 step 6 + §4.4.
 
@@ -820,6 +962,15 @@ def _phase_b_shard(
     unmeasured program — and an extra ``(waves, 2, 2)`` uint32 ticks
     output is appended. ``None`` (the default) compiles to the identical
     untimed program.
+
+    ``keyed`` (``MapReduceConfig.keyed_output``): the wire carries each
+    pair's key in place of its cluster id, and each slot reduces what it
+    received by key (:func:`_reduce_by_key`, chunk by chunk: a key lives in
+    one cluster, hence in one chunk). The outputs are then a table per
+    slot, ``(keys, values)`` and counts, one row per received pair.
+    ``counted``: the pairs are a combiner's, each with its count of map
+    pairs as the last value column (``MapReduceConfig.combine``). Neither
+    is timed or coded.
     """
     (num_slots, num_clusters, capacity, chunk_caps, reduce_op, pipelined,
      num_chunks, use_kernel, replication, quantize) = cfg_static
@@ -860,10 +1011,11 @@ def _phase_b_shard(
         ])
         return jax.lax.psum(vec, AXIS)[None]
 
+    payload = key_hashes if keyed else cluster_ids.astype(jnp.int32)
     if not pipelined or num_chunks <= 1:
         dest = jnp.where(valid, assignment[cluster_ids], num_slots).astype(jnp.int32)
         bv, bc, bm, overflow = _counting_sort_to_buckets(
-            dest, send_vals, cluster_ids.astype(jnp.int32), num_slots, capacity
+            dest, send_vals, payload, num_slots, capacity
         )
         # Bytes-on-the-wire: every bucketed row except the slot's own
         # diagonal bucket (delivered locally) crosses the network.
@@ -872,11 +1024,16 @@ def _phase_b_shard(
         rv, rc, rm = _copy_chunk((bv, bc, bm), v_dim)
         if quantize:
             rv = _quantize_decode(rv, scale, values.dtype, quantize)
+        if keyed:
+            keys, out, counts = _reduce_by_key(rc, rv, rm, reduce_op, counted)
+            return ((keys, out), counts, jax.lax.psum(overflow, AXIS)[None],
+                    _wire_vec(wire_rows))
         if timed:
             # Start stamp: produces the ids the reduce consumes.
             rc, start = stamp_through(rc)
         out, counts = _sequential_reduce(
-            rv, rc, rm, rank_of_cluster, num_clusters, reduce_op, use_kernel
+            rv, rc, rm, rank_of_cluster, num_clusters, reduce_op, use_kernel,
+            counted=counted,
         )
         if timed:
             # End stamp: consumes + re-emits the outputs (bit-identical),
@@ -900,7 +1057,7 @@ def _phase_b_shard(
     group_caps = np.repeat(np.asarray(chunk_caps, np.int64), num_slots)
     total = int(group_caps.sum())
     fv, fc, fm, overflow = _ragged_counting_sort_to_buckets(
-        group, send_vals, cluster_ids.astype(jnp.int32), group_caps, total
+        group, send_vals, payload, group_caps, total
     )
     send = []
     wire_rows = jnp.zeros((), jnp.float32)
@@ -930,6 +1087,7 @@ def _phase_b_shard(
     # outputs through instead, so it lands after the last reduce.
     boundaries = []
     prev_out = None
+    tables = []
     recv = _copy_chunk(send[0], v_dim)
     for c in range(num_chunks):
         rv, rc, rm = recv
@@ -939,6 +1097,9 @@ def _phase_b_shard(
             recv = _copy_chunk(send[c + 1], v_dim)
         if quantize:
             rv = _quantize_decode(rv, scale, values.dtype, quantize)
+        if keyed:
+            tables.append(_reduce_by_key(rc, rv, rm, reduce_op, counted))
+            continue
         if timed:
             anchors = () if prev_out is None else (prev_out[0][0, 0],
                                                    prev_out[1][0])
@@ -946,7 +1107,7 @@ def _phase_b_shard(
             boundaries.append(b)
         out_c, cnt_c = _reduce_chunk(
             rv, rc, rm, rank_of_cluster, num_clusters,
-            reduce_op, use_kernel,
+            reduce_op, use_kernel, counted=counted,
         )
         if timed and c + 1 == num_chunks:
             # Final boundary: re-emit the last outputs (bit-identical) so
@@ -963,6 +1124,10 @@ def _phase_b_shard(
         else:
             acc = acc + out_c.astype(acc_dtype)
         cnt = cnt + cnt_c.astype(jnp.float32)
+    if keyed:
+        keys, out, counts = (jnp.concatenate(t) for t in zip(*tables))
+        return ((keys, out), counts, jax.lax.psum(overflow, AXIS)[None],
+                _wire_vec(wire_rows))
     if timed:
         ticks = jnp.stack([
             jnp.stack([boundaries[c], boundaries[c + 1]])
@@ -1071,15 +1236,22 @@ class MapReduceJob:
                 "wave recovery zeroes completed per-cluster histogram "
                 "columns, which a count-min counter grid does not have"
             )
-        self._phase_a = functools.partial(
-            _phase_a_shard,
-            map_fn=self.map_fn,
-            num_clusters=cfg.num_clusters,
-            stats_fn=self._stats.collect,
-            prefix_fraction=cfg.stream_prefix,
-        )
+        if cfg.combine:
+            # Phase A is the map alone; the statistics count combined pairs.
+            self._phase_a = functools.partial(_map_shard, map_fn=self.map_fn)
+        else:
+            self._phase_a = functools.partial(
+                _phase_a_shard,
+                map_fn=self.map_fn,
+                num_clusters=cfg.num_clusters,
+                stats_fn=self._stats.collect,
+                prefix_fraction=cfg.stream_prefix,
+            )
+        # The combiner's per-shard capacity C (see _combine).
+        self._combine_cap: Optional[int] = None
         # Overflow escape hatches taken for estimate-committed capacities
-        # (prefix-planned wave-1 caps; see _escalate_caps). Telemetry —
+        # (prefix-planned wave-1 caps; see _escalate_caps) and for a
+        # combiner capacity a shard outgrew (see _combine). Telemetry —
         # distinct from ScheduleCache.capacity_fallbacks, which counts
         # reused-plan overflows.
         self.capacity_fallbacks = 0
@@ -1181,6 +1353,25 @@ class MapReduceJob:
                 "quantize_shuffle is incompatible with checkpoint_waves —"
                 " the checkpointed copy programs ship the exact wire"
             )
+        # The combiner and the per-key Reduce run on the fused jnp phase-B
+        # executor only (the other executors reduce per cluster id).
+        if cfg.combine or cfg.keyed_output:
+            unsupported = {
+                "checkpoint_waves": cfg.checkpoint_waves,
+                "shuffle_replication > 1": cfg.shuffle_replication > 1,
+                "quantize_shuffle": cfg.quantize_shuffle is not None,
+                "measured timings": self._measure_timings,
+                "use_kernels": cfg.use_kernels,
+                # A prefix of the combined, key-sorted pairs is no sample of
+                # the pairs that land first.
+                "stream_prefix": cfg.combine and cfg.stream_prefix is not None,
+            }
+            for name, on in unsupported.items():
+                if on:
+                    raise ValueError(
+                        f"combine and keyed_output run on the fused jnp phase-B"
+                        f" executor only; {name} is not supported with them"
+                    )
         # Last measured (wire bytes, non-local pairs) — turns the cost
         # model's modeled bytes/pair into a measured rate on the next plan.
         self._last_wire: Optional[Tuple[int, int]] = None
@@ -2015,16 +2206,19 @@ class MapReduceJob:
             planned.waves.replication, cfg.quantize_shuffle,
         )
 
+        keyed, counted = cfg.keyed_output, cfg.combine
+
         def phase_b(intermediate, assignment, rank_of_cluster, chunk_of_cluster):
             """Per-shard chunked shuffle + pipelined reduce under ``static``."""
             return _phase_b_shard(
-                intermediate, assignment, rank_of_cluster, chunk_of_cluster, static
+                intermediate, assignment, rank_of_cluster, chunk_of_cluster, static,
+                keyed=keyed, counted=counted,
             )
 
         return self._run_sharded(
             phase_b,
             ((0, 0, 0), None, None, None),
-            (0, 0, 0, 0),
+            ((0, 0) if keyed else 0, 0, 0, 0),
             intermediate,
             jnp.asarray(planned.schedule.assignment, jnp.int32),
             jnp.asarray(planned.waves.rank_of_cluster),
@@ -2498,6 +2692,49 @@ class MapReduceJob:
         self.last_replayed_waves = replayed
         return vals, cnts, overflow_total
 
+    def _combine(self, pairs):
+        """Hadoop's combiner over the map's pairs: ``(combined, local_k)``.
+
+        Dispatches ``jit_combine`` at the per-shard capacity C and pulls the
+        shards' counts of combined pairs, which are exact whatever C is. A
+        shard with more than C re-runs the combiner at the next power of
+        two at or above the largest count, which holds every shard's pairs,
+        so no pair is dropped; C keeps that value. C starts at 1 and only
+        grows, so the combiner's and phase B's executables settle within
+        the first batches. (K itself is no start: phase B's buffers for K
+        combined pairs of two value columns take the whole of a 16 GB chip.)
+        """
+        k = int(pairs[0].shape[-1])
+        cap = self._combine_cap or 1
+        with TraceAnnotation(spans.COMBINE) as span:
+            combined, local_k, runs = self._run_combine(pairs, cap)
+            runs = _pull(spans.STATS_PULL, runs).reshape(-1)
+            most = int(runs.max())
+            if most > cap:
+                self.capacity_fallbacks += 1
+                cap = min(k, 1 << (most - 1).bit_length())
+                combined, local_k, _ = self._run_combine(pairs, cap)
+            span.set_metadata(capacity=cap, combined_pairs=int(runs.sum()))
+        self._combine_cap = cap
+        return combined, local_k
+
+    def _run_combine(self, pairs, capacity: int):
+        """The ``jit_combine`` executable at per-shard ``capacity``."""
+        cfg = self.cfg
+        # The combined pairs keep the caller-side layout, a leading (m,)
+        # axis, on either backend (shard_map concatenates flat, so each
+        # shard re-adds a leading 1), as phase B expects of its input.
+        lead = (lambda a: a) if self.backend == "vmap" else (lambda a: a[None])
+
+        def combine(pairs):
+            """Per-shard combiner and statistics (see _combine_shard)."""
+            combined, state, runs = _combine_shard(
+                pairs, capacity, cfg.num_clusters, self._stats.collect, cfg.reduce_op)
+            return tuple(lead(a) for a in combined), state, runs
+
+        return self._run_sharded(combine, ((0, 0, 0),), ((0, 0, 0), 0, 0), pairs,
+                                 cache_key=("combine", capacity))
+
     def _decide(self, cache: sc.ScheduleCache, local_k):
         """Reuse or replan this batch: the drift check, then the cost gate.
 
@@ -2573,9 +2810,14 @@ class MapReduceJob:
             """Per-shard map + local K^(i) histogram (phase A body)."""
             return self._phase_a(shard_input)
 
-        intermediate, local_k = self._run_sharded(
-            phase_a, (0,), ((0, 0, 0), 0), inputs, cache_key=("a",)
-        )
+        if cfg.combine:
+            pairs = self._run_sharded(phase_a, (0,), (0, 0, 0), inputs,
+                                      cache_key=("a",))
+            intermediate, local_k = self._combine(pairs)
+        else:
+            intermediate, local_k = self._run_sharded(
+                phase_a, (0,), ((0, 0, 0), 0), inputs, cache_key=("a",)
+            )
         # Per-shard provider state, still on device: (m, S) for vmap, a
         # flat global axis under shard_map — reshape covers both. S is the
         # provider's state size (n exact, depth*width sketch); streaming
@@ -2696,9 +2938,9 @@ class MapReduceJob:
                 cache.store(planned)
             out, counts, wire_vec, timings, overflow_total = execute(planned)
 
+        keys = None
         if not checkpointing:
-            pulled = out.nbytes + counts.nbytes + (
-                wire_vec.nbytes if wire_vec is not None else 0)
+            pulled = sum(x.nbytes for x in jax.tree.leaves((out, counts, wire_vec)))
             with TraceAnnotation(spans.OUTPUT_PULL, bytes=pulled):
                 out, counts, wire_vec = jax.device_get((out, counts, wire_vec))
         with TraceAnnotation(spans.MERGE):
@@ -2717,7 +2959,15 @@ class MapReduceJob:
 
             # Each cluster is reduced on exactly one slot; merge = sum over
             # slots (the checkpointed executor already merged wave-by-wave).
-            if not checkpointing:
+            if cfg.keyed_output:
+                # Each key is reduced on one slot: its rows are those with
+                # a count, in slot order.
+                counts_np = np.asarray(counts).reshape(-1)
+                has = counts_np > 0
+                keys = np.asarray(out[0]).reshape(-1)[has]
+                values = np.asarray(out[1]).reshape(counts_np.size, -1)[has]
+                counts_np = counts_np[has]
+            elif not checkpointing:
                 values = np.asarray(out).reshape(m, n, -1).sum(axis=0)
                 counts_np = np.asarray(counts).reshape(m, n).sum(axis=0)
 
@@ -2763,4 +3013,5 @@ class MapReduceJob:
                 shuffle_pairs=shuffle_pairs,
                 replication_bytes=replication_bytes,
                 quantize_exact=quantize_exact,
+                keys=keys,
             )

@@ -8,6 +8,9 @@ no session each costs about a microsecond. Their keyword arguments become
 stats of the trace event (the counters below). One batch is one tree:
 
     os4m.batch (batch)                  the whole of ``run()``
+      os4m.combine (capacity, combined_pairs)
+                                        the combiner's dispatch and any re-run at K
+        os4m.stats_pull (bytes)         per-shard combined pair counts
       os4m.decide                       drift check and cost gate
         os4m.stats_pull (bytes)         only when the cost gate pulls K^(i)
       os4m.stats_pull (bytes)           K^(i), its column sum or the prefix sketch
@@ -23,7 +26,11 @@ the host; ``valid_pairs`` the pairs the statistics count (sum of the key
 distribution) and ``input_pairs`` the slots' map outputs (m x k), so their
 ratio is the share of the spilled pairs that are real; ``key`` the first
 element of the jit-cache key (``"a"`` phase A, ``"b"`` phase B, ...).
-``os4m.jit_build`` also opens inside ``os4m.batch`` for phase A.
+``os4m.jit_build`` also opens inside ``os4m.batch`` for phase A and inside
+``os4m.combine`` for the combiner. ``os4m.combine`` opens only under
+``MapReduceConfig(combine=True)``: ``capacity`` is the static per-shard
+capacity C its output was compacted to, ``combined_pairs`` the combined
+pairs of all shards, the loads that the statistics then count.
 
 Readers: ``bench/program_spans.py`` intersects the host spans with the
 device's idle gaps for the per-layer metrics ``host_plan_ms``
@@ -34,9 +41,11 @@ its two innermost spans; ``bench/tools/span_report.py`` prints every span,
 its counters and the device scopes per batch.
 
 Device scopes are ``jax.named_scope`` in the phase-B helpers, so every
-phase-B variant carries them. They change op metadata only (the HLO
+phase-B variant carries them, and in the combiner (``os4m.combine``, the
+``jit_combine`` executable). They change op metadata only (the HLO
 ``op_name`` and a device trace's ``tf_op`` stat), not fusion or run time.
-Read by ``bench/tools/span_report.py``.
+``bench/tools/span_report.py`` reads the three phase-B scopes; the
+combiner's device time is read from its executable (``combine_ms``).
 """
 
 BATCH = "os4m.batch"
@@ -47,10 +56,11 @@ PLAN_ASSIGN = "os4m.plan.assign"
 PHASE_B = "os4m.phase_b"
 OUTPUT_PULL = "os4m.output_pull"
 MERGE = "os4m.merge"
+COMBINE = "os4m.combine"  # also the device scope of the combiner's reduce by key
 JIT_BUILD = "os4m.jit_build"
 
 SPILL = "os4m.spill"    # rank each pair in its (chunk, slot) group and bucket it
 COPY = "os4m.copy"      # the all-to-all of a chunk (and the coded path's exchanges)
 REDUCE = "os4m.reduce"  # the segment reduce of a chunk, or of the whole input
 
-DEVICE_SCOPES = (SPILL, COPY, REDUCE)
+DEVICE_SCOPES = (SPILL, COPY, REDUCE, COMBINE)

@@ -177,8 +177,59 @@ def test_phase_b_hlo_carries_the_scopes(pipelined):
                          per_cluster).compile().as_text()
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for scope in spans.DEVICE_SCOPES:
+        if scope == spans.COMBINE:  # the combiner's own executable, below
+            continue
         rx = re.compile(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)")
         assert any(rx.search(n) for n in op_names), scope
+
+
+def test_combiner_span_and_scope():
+    """Under ``combine``: one ``os4m.combine`` span a batch, before the
+    drift check, with the capacity and the combined pairs (the loads the
+    plan counts), the count pull inside it; its executable carries the
+    ``os4m.combine`` scope."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.core import spans
+    from repro.core.mapreduce import MapReduceConfig, MapReduceJob
+    from repro.core.schedule_cache import ReusePolicy
+
+    job = MapReduceJob(_identity_map, MapReduceConfig(
+        num_slots=M, num_clusters=N, pipeline_chunks=3, reuse=ReusePolicy(),
+        combine=True, keyed_output=True), backend="vmap")
+    batch = _batch(0, 1.3)
+    distinct = sum(np.unique(np.asarray(k)).size for k in batch[0])
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(2):
+                job.run(batch)
+        path = sorted(Path(d).rglob("*.xplane.pb"))[-1]
+        events = sorted(([e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)]
+                         for p in ProfileData.from_file(str(path)).planes
+                         if p.name == "/host:CPU" for ln in p.lines for e in ln.events
+                         if e.name.startswith("os4m.")), key=lambda e: (e[1], -e[2]))
+    rec = {"events": events}
+    for i, batch_span in enumerate(_batches(rec)):
+        inner = _inside(rec, batch_span)
+        names = [e[0] for e in inner]
+        combine = inner[names.index(spans.COMBINE)]
+        assert names.count(spans.COMBINE) == 1
+        assert names.index(spans.COMBINE) < names.index(spans.DECIDE)
+        assert combine[3]["combined_pairs"] == distinct
+        assert combine[3]["capacity"] == 1 << (max(np.unique(np.asarray(k)).size
+                                                   for k in batch[0]) - 1).bit_length()
+        assert any(e[0] == spans.STATS_PULL and combine[1] <= e[1] and e[2] <= combine[2]
+                   for e in inner)
+        plan = [e for e in inner if e[0] == spans.PLAN]
+        if i == 0:
+            assert plan[0][3]["valid_pairs"] == distinct
+    fn = job._jit_cache[("combine", job._combine_cap)]
+    pairs = job._jit_cache[("a",)](batch)
+    text = fn.lower(pairs).compile().as_text()
+    assert any(re.search(r"(^|[/(])os4m\.combine([/)]|$)", n)
+               for n in re.findall(r'op_name="([^"]*)"', text))
 
 
 if __name__ == "__main__":
