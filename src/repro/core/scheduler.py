@@ -40,6 +40,10 @@ Implemented strategies (all return a :class:`Schedule`):
                                decomposition into per-slot Balanced Subset Sum
                                problems with speed-proportional targets,
                                solved with an ``eta``-FPTAS.
+* :func:`schedule_os4m`      — what ``"os4m"`` names: LPT when a lower bound
+                               (:func:`makespan_lower_bound`) certifies it
+                               within ``1 + eta`` of the optimum, else
+                               :func:`schedule_bss`.
 * :func:`schedule_brute`     — exact branch-and-bound over finish times for
                                tiny instances (test oracle).
 * :func:`lpt_assign_jax`     — a JAX-traceable earliest-finish-time LPT usable
@@ -59,6 +63,7 @@ from typing import Callable, Dict, Optional, Sequence
 import numpy as np
 
 from repro.core import bss as _bss
+from repro.core import spans as _spans
 
 __all__ = [
     "Schedule",
@@ -71,6 +76,8 @@ __all__ = [
     "schedule_lpt",
     "schedule_multifit",
     "schedule_bss",
+    "schedule_os4m",
+    "makespan_lower_bound",
     "schedule_brute",
     "schedule_unrelated",
     "get_scheduler",
@@ -793,6 +800,19 @@ def schedule_bss(
         return Schedule.from_assignment(
             alive[inner.assignment], loads, num_slots, speeds=speeds
         )
+    return _bss_alive(loads, num_slots, eta, refine, speeds)
+
+
+def _bss_alive(
+    loads: np.ndarray,
+    num_slots: int,
+    eta: float,
+    refine: bool,
+    speeds: Optional[Sequence[float]],
+    lpt: Optional[Schedule] = None,
+) -> Schedule:
+    """:func:`schedule_bss` on slots that are all alive; ``lpt`` is
+    ``schedule_lpt(loads, num_slots, speeds=speeds)`` when the caller has it."""
     s = _speeds_or_ones(speeds, num_slots)
     n = loads.shape[0]
     assignment = np.full(n, -1, dtype=np.int32)
@@ -835,10 +855,76 @@ def schedule_bss(
         # The DP decomposition is near-optimal on skewed instances but can
         # lose to plain LPT on tiny/uniform ones; both are cheap host-side,
         # so keep whichever schedule is better (never worse than LPT).
-        lpt = schedule_lpt(loads, num_slots, speeds=speeds)
+        if lpt is None:
+            lpt = schedule_lpt(loads, num_slots, speeds=speeds)
         if lpt.makespan < sched.makespan:
             sched = lpt
     return sched
+
+
+def makespan_lower_bound(
+    loads: Sequence[float],
+    num_slots: int,
+    speeds: Optional[Sequence[float]] = None,
+) -> float:
+    """A lower bound on the optimal Q||C_max makespan, over the alive slots.
+
+    With loads ``w_1 >= w_2 >= ...`` and alive speeds ``s_1 >= s_2 >= ...``
+    (``m`` of them): the ``k`` largest operations lie on at most ``k``
+    slots, so the optimum is at least ``(w_1 + ... + w_k) / (s_1 + ... +
+    s_k)`` for ``k < m``, and at least ``Σw / Σs``; two of the ``m + 1``
+    largest share a slot, so it is at least ``(w_m + w_{m+1}) / s_1``.
+    Uniform speeds give ``max(total / m, w_1, w_m + w_{m+1})``.
+    """
+    w = np.sort(np.asarray(loads, dtype=np.float64))[::-1]
+    s = _speeds_or_ones(speeds, num_slots)
+    s = np.sort(s[s > 0.0])[::-1]
+    m = s.size
+    if w.size == 0:
+        return 0.0
+    bound = w.sum() / s.sum()
+    k = min(m - 1, w.size)
+    if k:
+        bound = max(bound, float(np.max(np.cumsum(w[:k]) / np.cumsum(s[:k]))))
+    if w.size > m:
+        bound = max(bound, (w[m - 1] + w[m]) / s[0])
+    return float(bound)
+
+
+def schedule_os4m(
+    loads: Sequence[float],
+    num_slots: int,
+    eta: float = 0.002,
+    refine: bool = True,
+    speeds: Optional[Sequence[float]] = None,
+) -> Schedule:
+    """OS4M's assignment: LPT where it is certified within ``eta``, else BSS.
+
+    LPT runs first. If its makespan is at most ``(1 + eta)`` times
+    :func:`makespan_lower_bound`, it is within ``eta`` of the optimum,
+    the guarantee the BSS FPTAS gives, and it is returned. Otherwise the
+    result is :func:`schedule_bss`'s, which reuses this LPT schedule
+    rather than computing it again; the BSS DP then runs inside the host
+    span ``os4m.plan.bss``. Skewed loads whose heavy operations LPT
+    isolates are certified; a few similar large operations are not.
+    """
+    loads = np.asarray(loads, dtype=np.float64)
+    dead = _dead_slot_split(speeds, num_slots)
+    if dead is not None:
+        alive, s_alive = dead
+        inner = schedule_os4m(
+            loads, alive.size, eta=eta, refine=refine, speeds=s_alive
+        )
+        return Schedule.from_assignment(
+            alive[inner.assignment], loads, num_slots, speeds=speeds
+        )
+    lpt = schedule_lpt(loads, num_slots, speeds=speeds)
+    if lpt.makespan <= (1.0 + eta) * makespan_lower_bound(loads, num_slots, speeds):
+        return lpt
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(_spans.PLAN_BSS):
+        return _bss_alive(loads, num_slots, eta, refine, speeds, lpt=lpt)
 
 
 def _refine_moves(sched: Schedule, loads: np.ndarray, max_moves: int = 256) -> Schedule:
@@ -1088,8 +1174,8 @@ SCHEDULERS: Dict[str, Callable[..., Schedule]] = {
     "hash": schedule_hash,
     "lpt": schedule_lpt,
     "multifit": schedule_multifit,
-    "bss": schedule_bss,
-    "os4m": schedule_bss,  # alias: the paper's method
+    "bss": schedule_bss,  # the paper's DP, always run
+    "os4m": schedule_os4m,  # the paper's method, its DP skipped where LPT is certified
     "unrelated": schedule_unrelated,  # R||C_max native (multi-job R-matrix)
 }
 

@@ -15,7 +15,8 @@ stats of the trace event (the counters below). One batch is one tree:
         os4m.stats_pull (bytes)         only when the cost gate pulls K^(i)
       os4m.stats_pull (bytes)           K^(i), its column sum or the prefix sketch
       os4m.plan (valid_pairs, input_pairs)
-        os4m.plan.assign                the scheduler (hash / sketch / BSS / auto)
+        os4m.plan.assign                the scheduler (hash / sketch / OS4M / auto)
+          os4m.plan.bss                 OS4M's BSS DP, where LPT is not certified
       os4m.phase_b                      each dispatch of a phase-B executor
         os4m.jit_build (key)            first call of a newly built executable
       os4m.output_pull (bytes)          overflow count, outputs and counts, wire vector
@@ -31,14 +32,19 @@ element of the jit-cache key (``"a"`` phase A, ``"b"`` phase B, ...).
 ``MapReduceConfig(combine=True)``: ``capacity`` is the static per-shard
 capacity C its output was compacted to, ``combined_pairs`` the combined
 pairs of all shards, the loads that the statistics then count.
+``os4m.plan.bss`` is opened by :func:`repro.core.scheduler.schedule_os4m`,
+only when LPT's makespan is not within ``1 + eta`` of its lower bound and
+the BSS DP runs.
 
 Readers: ``bench/program_spans.py`` intersects the host spans with the
 device's idle gaps for the per-layer metrics ``host_plan_ms``
 (``os4m.plan``), ``drift_check_ms`` (``os4m.decide``), ``host_pull_ms``
 (``os4m.stats_pull`` and ``os4m.output_pull``) and ``host_merge_ms``
-(``os4m.merge``); ``bench/trace_reduce.breakdown`` labels each idle gap by
-its two innermost spans; ``bench/tools/span_report.py`` prints every span,
-its counters and the device scopes per batch.
+(``os4m.merge``); ``bench/metrics/plan_fallback_share.py`` counts the
+``os4m.plan.bss`` spans per ``os4m.plan`` span;
+``bench/trace_reduce.breakdown`` labels each idle gap by its two
+innermost spans; ``bench/tools/span_report.py`` prints every span, its
+counters and the device scopes per batch.
 
 Device scopes are ``jax.named_scope`` in the phase-B helpers, so every
 phase-B variant carries them, and in the combiner (``os4m.combine``, the
@@ -53,6 +59,7 @@ DECIDE = "os4m.decide"
 STATS_PULL = "os4m.stats_pull"
 PLAN = "os4m.plan"
 PLAN_ASSIGN = "os4m.plan.assign"
+PLAN_BSS = "os4m.plan.bss"
 PHASE_B = "os4m.phase_b"
 OUTPUT_PULL = "os4m.output_pull"
 MERGE = "os4m.merge"

@@ -55,28 +55,29 @@ def _job(backend: str):
         reuse=ReusePolicy(max_drift=0.2)), backend=backend, mesh=mesh)
 
 
+def _host_events(log_dir: str) -> list:
+    """The ``os4m.*`` host events of the trace in ``log_dir``, in start order:
+    ``[name, start_ns, end_ns, stats]``."""
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    return sorted(([e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)]
+                   for p in ProfileData.from_file(str(path)).planes
+                   if p.name == "/host:CPU" for ln in p.lines for e in ln.events
+                   if e.name.startswith("os4m.")), key=lambda e: (e[1], -e[2]))
+
+
 def record(backend: str, log_dir: str) -> dict:
     """Three batches of a fresh job under the profiler: the ``os4m.*`` host
     events, each batch's ``reused`` flag, and the job's jit misses."""
     import jax
-    from jax.profiler import ProfileData
 
     job = _job(backend)
     a, drifted = _batch(0, 1.3), _batch(1, 3.0)
     with jax.profiler.trace(log_dir):
         reused = [job.run(b).reused for b in (a, a, drifted)]
-    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
-    events = []
-    for plane in ProfileData.from_file(str(path)).planes:
-        if plane.name != "/host:CPU":
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith("os4m."):
-                    events.append([e.name, e.start_ns, e.start_ns + e.duration_ns,
-                                   dict(e.stats)])
-    events.sort(key=lambda e: (e[1], -e[2]))
-    return {"events": events, "reused": reused, "jit_misses": job.jit_misses}
+    return {"events": _host_events(log_dir), "reused": reused,
+            "jit_misses": job.jit_misses}
 
 
 def _recorded(backend: str) -> dict:
@@ -189,8 +190,6 @@ def test_combiner_span_and_scope():
     plan counts), the count pull inside it; its executable carries the
     ``os4m.combine`` scope."""
     import jax
-    import jax.numpy as jnp
-    from jax.profiler import ProfileData
 
     from repro.core import spans
     from repro.core.mapreduce import MapReduceConfig, MapReduceJob
@@ -205,12 +204,7 @@ def test_combiner_span_and_scope():
         with jax.profiler.trace(d):
             for _ in range(2):
                 job.run(batch)
-        path = sorted(Path(d).rglob("*.xplane.pb"))[-1]
-        events = sorted(([e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)]
-                         for p in ProfileData.from_file(str(path)).planes
-                         if p.name == "/host:CPU" for ln in p.lines for e in ln.events
-                         if e.name.startswith("os4m.")), key=lambda e: (e[1], -e[2]))
-    rec = {"events": events}
+        rec = {"events": _host_events(d)}
     for i, batch_span in enumerate(_batches(rec)):
         inner = _inside(rec, batch_span)
         names = [e[0] for e in inner]
@@ -230,6 +224,44 @@ def test_combiner_span_and_scope():
     text = fn.lower(pairs).compile().as_text()
     assert any(re.search(r"(^|[/(])os4m\.combine([/)]|$)", n)
                for n in re.findall(r'op_name="([^"]*)"', text))
+
+
+def _loads_batch(loads):
+    """One batch whose cluster ``c`` holds ``loads[c]`` valid pairs."""
+    import jax.numpy as jnp
+
+    keys = np.repeat(np.arange(len(loads)), loads).astype(np.int32)
+    valid = np.arange(M * K) < keys.size
+    keys = np.pad(keys, (0, M * K - keys.size))
+    return (jnp.asarray(keys.reshape(M, K)), jnp.ones((M, K, 2), jnp.float32),
+            jnp.asarray(valid.reshape(M, K)))
+
+
+def test_bss_span_opens_only_where_lpt_is_not_certified():
+    """``os4m.plan.bss`` opens inside ``os4m.plan.assign`` on loads where
+    LPT is not within ``eta`` of its lower bound (four of 300 and six of
+    200 pairs: LPT 700, bound 600), and not on loads where it is (32
+    clusters of 128 pairs: LPT at the bound)."""
+    import jax
+
+    from repro.core import spans
+    from repro.core.mapreduce import MapReduceConfig, MapReduceJob
+
+    job = MapReduceJob(_identity_map, MapReduceConfig(
+        num_slots=M, num_clusters=N, pipeline_chunks=3), backend="vmap")
+    fallback, certified = _loads_batch([300] * 4 + [200] * 6), _loads_batch([128] * N)
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for batch in (fallback, certified):
+                job.run(batch)
+        rec = {"events": _host_events(d)}
+    first, second = (_inside(rec, b) for b in _batches(rec))
+    bss = [e for e in first if e[0] == spans.PLAN_BSS]
+    assign = [e for e in first if e[0] == spans.PLAN_ASSIGN]
+    assert len(bss) == len(assign) == 1
+    assert assign[0][1] <= bss[0][1] and bss[0][2] <= assign[0][2]
+    names = [e[0] for e in second]
+    assert spans.PLAN_ASSIGN in names and spans.PLAN_BSS not in names
 
 
 if __name__ == "__main__":

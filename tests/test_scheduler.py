@@ -1,6 +1,7 @@
 """P||C_max scheduler unit + property tests (paper §3.2/§4.2)."""
 
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import bss, scheduler as S
@@ -130,3 +131,73 @@ def test_lpt_assign_jax_matches_host():
     host = S.schedule_lpt(loads, 3)
     got = np.bincount(np.asarray(assign), weights=loads, minlength=3)
     assert np.isclose(sorted(got)[-1], host.max_load)
+
+
+# ---------------------------------------------------------------------------
+# schedule_os4m: LPT where the lower bound certifies it, else BSS.
+# ---------------------------------------------------------------------------
+
+SPEEDS = {"uniform": None, "uneven": (1.0, 0.5, 2.0, 1.5), "dead": (1.0, 0.0, 2.0, 1.0)}
+
+
+def _os4m_is_certified_lpt_or_bss(loads, m, speeds, eta=0.002) -> bool:
+    """``schedule_os4m`` is LPT's schedule, within ``1 + eta`` of the lower
+    bound, where that bound certifies LPT, and ``schedule_bss``'s otherwise;
+    returns whether LPT was certified."""
+    got = S.schedule_os4m(loads, m, eta=eta, speeds=speeds)
+    lpt = S.schedule_lpt(loads, m, speeds=speeds)
+    lb = S.makespan_lower_bound(loads, m, speeds)
+    certified = lpt.makespan <= (1 + eta) * lb
+    want = lpt if certified else S.schedule_bss(loads, m, eta=eta, speeds=speeds)
+    np.testing.assert_array_equal(got.assignment, want.assignment)
+    if certified:
+        assert got.makespan <= (1 + eta) * lb
+    assert got.makespan <= lpt.makespan
+    if speeds is not None:
+        assert not got.slot_loads[np.asarray(speeds) == 0].any()
+    return certified
+
+
+def _q15_loads(seed: int) -> np.ndarray:
+    """Loads shaped like TPC-H Q15's: 158,500 valid pairs, Zipf z = 1 over
+    10,000 suppliers, one supplier a cluster of 16,384."""
+    rng = np.random.default_rng(seed)
+    p = 1.0 / np.arange(1, 10_001)
+    suppliers = rng.permutation(10_000)[rng.choice(10_000, 158_500, p=p / p.sum())]
+    return np.bincount(suppliers, minlength=16_384).astype(float)
+
+
+@pytest.mark.parametrize("kind", sorted(SPEEDS))
+@given(st.lists(st.integers(1, 50), min_size=2, max_size=10),
+       st.integers(2, 4))
+@settings(max_examples=60, deadline=None)
+def test_os4m_lower_bound_and_result_on_brute_force_instances(kind, loads, m):
+    """The bound never passes the exact optimum; the result is certified
+    LPT or BSS, and never worse than LPT."""
+    loads = np.asarray(loads, dtype=float)
+    speeds = None if SPEEDS[kind] is None else SPEEDS[kind][:m]
+    opt = S.schedule_brute(loads, m, speeds=speeds).makespan
+    assert S.makespan_lower_bound(loads, m, speeds) <= opt * (1 + 1e-12)
+    _os4m_is_certified_lpt_or_bss(loads, m, speeds)
+
+
+@pytest.mark.parametrize("loads, m, speeds, certified", [
+    ([3, 3, 2, 2, 2], 2, None, False),                # LPT 7, bound 6
+    ([3, 3, 2, 2, 2], 3, (1.0, 0.0, 1.0), False),     # the same on the alive slots
+    ([7, 6, 5, 4, 4, 4], 2, (1.5, 1.0), False),       # LPT 13.33, bound 12
+    ([4, 2, 2], 3, (2.0, 1.0, 1.0), True),            # LPT at the bound, 2
+    ([1] * 12, 4, (1.0, 0.0, 1.0, 1.0), True),        # 4 on each alive slot
+])
+def test_os4m_small_instances(loads, m, speeds, certified):
+    assert _os4m_is_certified_lpt_or_bss(np.asarray(loads, float), m, speeds) is certified
+
+
+@pytest.mark.parametrize("m", [8, 4])
+def test_os4m_certifies_q15_shaped_loads(m):
+    """On Q15's loads LPT is certified, and as good as the BSS DP."""
+    loads = _q15_loads(0)
+    assert _os4m_is_certified_lpt_or_bss(loads, m, None)
+    got = S.schedule_os4m(loads, m)
+    assert got.finish_ratio == S.schedule_bss(loads, m).finish_ratio
+    assert S.get_scheduler("os4m") is S.schedule_os4m
+    assert S.get_scheduler("bss") is S.schedule_bss
