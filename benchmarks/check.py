@@ -179,37 +179,6 @@ def gate_multijob(path: str = "BENCH_multijob.json") -> None:
           f"collisions={r['cache']['collisions']}")
 
 
-def gate_shuffle_volume(path: str = "BENCH_shuffle_volume.json") -> None:
-    """Coded shuffle: measured wire-byte cut, bit-identity, bounded wall.
-
-    ``wall_ok`` is computed by the bench (factor + absolute CPU-compute
-    allowance — see ``SHUFFLE_WALL_FACTOR`` in benchmarks/run.py); the
-    gate asserts the verdict and prints the raw ratio for the record.
-    """
-    r = _load(path)
-    require("shuffle-volume", r["bit_identical"],
-            "coded (r=2) outputs == uncoded outputs", r["bit_identical"])
-    require("shuffle-volume", r["bytes_reduction"] >= 1.5,
-            "measured wire bytes cut >= 1.5x at r=2",
-            f"{r['bytes_reduction']:.2f}x")
-    require("shuffle-volume", r["wall_ok"],
-            "coded wall clock within factor+slack of uncoded",
-            f"x{r['wall_ratio']:.2f}")
-    require("shuffle-volume", r["coded"]["replication_bytes"] > 0,
-            "replica-exchange bytes accounted separately (> 0)",
-            r["coded"]["replication_bytes"])
-    require("shuffle-volume", r["quantized"]["bit_identical"],
-            "coded int8 outputs == uncoded int8 outputs",
-            r["quantized"]["bit_identical"])
-    print(f"wire bytes {r['uncoded']['shuffle_bytes']} -> "
-          f"{r['coded']['shuffle_bytes']} "
-          f"({r['bytes_reduction']:.2f}x) + "
-          f"{r['coded']['replication_bytes']} replica B, "
-          f"wall x{r['wall_ratio']:.2f}, "
-          f"int8 {r['quantized']['uncoded_bytes']} -> "
-          f"{r['quantized']['coded_bytes']} B")
-
-
 def gate_sketch(path: str = "BENCH_sketch.json") -> None:
     """Pluggable statistics: sketch plan-path cut, hatch rate, identity.
 
@@ -294,7 +263,6 @@ GATES: Dict[str, Callable[..., None]] = {
     "straggler-measured": gate_straggler_measured,
     "elastic": gate_elastic,
     "multijob": gate_multijob,
-    "shuffle-volume": gate_shuffle_volume,
     "sketch": gate_sketch,
     "docs-links": gate_docs_links,
 }

@@ -11,11 +11,12 @@ where untraced code paths (host planners, helpers) also live:
   *once, at trace time* — it bakes one arbitrary value into the compiled
   program, silently. Host callback bodies are exempt (they run on the
   host every call, which is the point).
-* **wire-sort-stability (C2)** — in the wire-shaping modules
-  (``core/mapreduce.py``, ``kernels/coded_shuffle``), every
-  ``argsort`` / ``lax.sort`` / ``sort_key_val`` call must spell its
-  stability (``stable=`` / ``is_stable=`` / numpy's ``kind=``). The
-  identical-sort contract (docs/SHUFFLE.md) must be visible in the
+* **wire-sort-stability (C2)** — in the wire-shaping module
+  (``core/mapreduce.py``), every ``argsort`` / ``lax.sort`` /
+  ``sort_key_val`` call must spell its stability (``stable=`` /
+  ``is_stable=`` / numpy's ``kind=``). Each cluster's pairs reach the
+  reduce in (src shard, bucket position) order on every executor only
+  because the spill's sort is stable; that must be visible in the
   source, not inherited from a default that jax has changed before.
 * **callback-marker (C3)** — every ``io_callback`` / ``pure_callback``
   call site carries an ``# analysis: allow-callback`` marker on the
@@ -46,7 +47,7 @@ _STABILITY_KWARGS = {"stable", "is_stable", "kind"}
 _MARKER = "# analysis: allow-callback"
 
 # Files whose sorts shape the shuffle wire (C2 scope).
-_WIRE_PARTS = ("core/mapreduce.py", "kernels/coded_shuffle")
+_WIRE_PARTS = ("core/mapreduce.py",)
 
 
 def _final_attr(func: ast.expr) -> Optional[str]:

@@ -10,13 +10,14 @@ program must resolve to a body registered in
 :mod:`repro.analysis.allowlist`. Today that registry holds exactly the
 two wave-timer stamp bodies.
 
-**unstable-wire-sort (D2)** — the coded shuffle's decode works only
-because sender and receiver run the *identical* sort over replicated
-records (docs/SHUFFLE.md's identical-sort wire contract), and ties are
-common (the spill key quantizes). Any ``sort`` equation with
-``is_stable=False`` that is entangled with the wire — an ``all_to_all``
-among its ancestors or its consumers — makes the wire
-permutation-dependent and is flagged with the connecting path.
+**unstable-wire-sort (D2)** — the sequential, pipelined and fenced
+executors are bit-identical only because each cluster's pairs reach its
+reduce in the same (src shard, bucket position) order on every path,
+and ties are common (the spill key is a (chunk, dest) group id). Any
+``sort`` equation with ``is_stable=False`` that is entangled with the
+wire — an ``all_to_all`` among its ancestors or its consumers — makes
+that order permutation-dependent and is flagged with the connecting
+path.
 
 **slab-dependent-blocking (D3)** — the PR 8 bug class: a Pallas grid or
 block shape derived from the data-dependent slab length recompiles per
@@ -51,7 +52,7 @@ def check_determinism(targets: Sequence,
     findings: List[Finding] = []
     for t in targets:
         findings.extend(_check_callbacks(t.name, t.graph, extra_allowed))
-        findings.extend(_check_wire_sorts(t.name, t.graph, coded=t.coded))
+        findings.extend(_check_wire_sorts(t.name, t.graph))
     findings.extend(check_slab_invariance(slab_build))
     return findings
 
@@ -79,7 +80,7 @@ def _check_callbacks(name: str, g: EqnGraph,
     return findings
 
 
-def _check_wire_sorts(name: str, g: EqnGraph, coded: bool) -> List[Finding]:
+def _check_wire_sorts(name: str, g: EqnGraph) -> List[Finding]:
     findings: List[Finding] = []
     a2a_ids = {n.id for n in g.by_prim("all_to_all")}
     for n in g.by_prim("sort"):
@@ -87,27 +88,23 @@ def _check_wire_sorts(name: str, g: EqnGraph, coded: bool) -> List[Finding]:
             continue
         # Entangled with the wire = an all_to_all upstream (the sort
         # orders received records) or downstream (the sort shapes what
-        # gets sent). In a coded trace every sort is wire-shaping.
+        # gets sent).
         up = g.ancestors_of(n.id) & a2a_ids
         down = g.reachable_from([n.id]) & a2a_ids
-        if not (coded or up or down):
+        if not (up or down):
             continue
         if up:
-            other = min(up)
-            chain = g.find_path(other, n.id)
-        elif down:
-            other = min(down)
-            chain = g.find_path(n.id, other)
+            chain = g.find_path(min(up), n.id)
         else:
-            chain = [n.id]
+            chain = g.find_path(n.id, min(down))
         findings.append(Finding(
             checker="determinism",
             rule="unstable-wire-sort",
             target=name,
             summary=(
                 "an unstable sort is entangled with the shuffle wire — "
-                "ties reorder freely, so sender and receiver can rebuild "
-                "different slabs (identical-sort contract broken)"),
+                "ties reorder freely, so a cluster's pairs can reach its "
+                "reduce in a different order on each executor"),
             evidence=g.describe_path(chain),
         ))
     return findings

@@ -66,12 +66,12 @@ class SelfTestResult:
         return f"{mark:7s} {self.name} -> [{self.checker}:{self.rule}]"
 
 
-def _fake_target(name: str, body, args, timed=False, coded=False):
+def _fake_target(name: str, body, args, timed=False):
     """Trace a mutant per-shard body into a TracedTarget-shaped object."""
     from repro.analysis.targets import TracedTarget
 
     closed = jg.trace_sharded(body, args, mr.AXIS, _M)
-    return TracedTarget(name, jg.EqnGraph(closed), timed=timed, coded=coded)
+    return TracedTarget(name, jg.EqnGraph(closed), timed=timed)
 
 
 def _x44():
@@ -133,7 +133,7 @@ def _mutant_unstable_sort():
         order = jnp.argsort(a[:, 0], stable=False)   # BUG: ties reorder
         return a[order]
 
-    t = _fake_target("mutant-unstable-sort", body, _x44(), coded=True)
+    t = _fake_target("mutant-unstable-sort", body, _x44())
     return determinism.check_determinism([t])
 
 
@@ -312,7 +312,7 @@ def _mutant_src_jit_time():
 
 
 def _mutant_src_wire_sort():
-    return _lint_snippet("kernels/coded_shuffle/encode.py", _SRC_WIRE_SORT)
+    return _lint_snippet("core/mapreduce.py", _SRC_WIRE_SORT)
 
 
 def _mutant_src_unmarked_cb():

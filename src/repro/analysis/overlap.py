@@ -8,10 +8,8 @@ chunk ``c`` computes. That overlap exists iff XLA is *free* to schedule
 them concurrently, i.e. iff no all-to-all equation transitively consumes
 another all-to-all's output: every reduce of chunk ``c`` depends on
 chunk ``c``'s all-to-all, so a ``reduce(c) → copy(c+1)`` edge would show
-up as exactly such a path. (This also covers the coded wire: the packet
-multicast is built from the sender's *own* spill, never from the replica
-exchange's output.) On violation the finding's evidence is the offending
-dependency chain, one equation per line.
+up as exactly such a path. On violation the finding's evidence is the
+offending dependency chain, one equation per line.
 
 **stamp-unanchored / stamp-pass-through-dropped** — a wave-timer stamp is
 only honest if true buffer dependencies pin it on both sides (PR 5's

@@ -11,10 +11,6 @@ rules mirror what the executors *assume* without re-checking:
 * **dead-slot-loaded** — a slot with speed exactly ``0.0`` has vanished
   from the mesh (elastic-mesh semantics); any assignment or load on it
   is work sent to a machine that no longer exists.
-* **invalid-pairing** — the coded shuffle's partner schedule
-  ``π(s, j) = (s + 1 + (j mod (m-1))) mod m`` must cover every other
-  slot exactly once per sender; otherwise some pair's XOR packet is
-  never decodable.
 * **chunk-cap-undersized** — send capacities were statistics-sized from
   the plan-time ``K^(i)``; a cap below the exact per-(shard, dest) worst
   case guarantees overflow on the very distribution the plan was built
@@ -51,11 +47,6 @@ def _finding(rule: str, target: str, summary: str, evidence) -> Finding:
                    summary=summary, evidence=list(evidence))
 
 
-def coded_partner(s: int, j: int, m: int) -> int:
-    """The engine's coded-shuffle pairing π (see ``core.mapreduce``)."""
-    return (s + 1 + (j % (m - 1))) % m
-
-
 def validate_wave_plan(plan, num_clusters: int, target: str) -> List[Finding]:
     """Wave-plan invariants: permutation rank, dense one-shot chunk ids."""
     findings: List[Finding] = []
@@ -88,12 +79,6 @@ def validate_wave_plan(plan, num_clusters: int, target: str) -> List[Finding]:
                 "waves and an empty one is a silent no-op stage",
                 [f"empty chunks: {empty} of num_chunks={plan.num_chunks}"],
             ))
-    if plan.replication not in (1, 2):
-        findings.append(_finding(
-            "bad-replication", target,
-            "wave-plan replication must be 1 (unicast) or 2 (XOR pairs)",
-            [f"replication={plan.replication}"],
-        ))
     return findings
 
 
@@ -119,31 +104,6 @@ def validate_membership(member_lists: Sequence[Sequence[int]],
          f"multiply-placed clusters: {dup}",
          f"out-of-range members: {stray}"],
     )]
-
-
-def validate_pairing(m: int, replication: int, target: str) -> List[Finding]:
-    """r=2 only: π must give every sender all other slots as partners."""
-    if replication != 2:
-        return []
-    if m < 2:
-        return [_finding(
-            "invalid-pairing", target,
-            "coded r=2 needs at least 2 slots to form multicast pairs",
-            [f"num_slots={m}"],
-        )]
-    findings: List[Finding] = []
-    for s in range(m):
-        partners = {coded_partner(s, j, m) for j in range(m - 1)}
-        expect = set(range(m)) - {s}
-        if partners != expect:
-            findings.append(_finding(
-                "invalid-pairing", target,
-                f"sender {s}'s partner schedule misses some slots — their "
-                "XOR packets are never decodable",
-                [f"partners under π: {sorted(partners)}",
-                 f"expected: {sorted(expect)}"],
-            ))
-    return findings
 
 
 def validate_schedule(schedule, target: str) -> List[Finding]:
@@ -233,7 +193,6 @@ def validate_snapshot(snap, target: str) -> List[Finding]:
     findings += validate_membership(
         [snap.waves.chunk_members(c) for c in range(snap.waves.num_chunks)],
         n, target)
-    findings += validate_pairing(m, snap.waves.replication, target)
 
     # Statistics-sized capacities: slack and octave quantization only
     # round up, so every cap must clear the worst case computed from the

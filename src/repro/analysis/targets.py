@@ -6,8 +6,6 @@ _phase_b_shard` and friends) in every execution variant the repo ships:
 
 * sequential (Hadoop-style single-shot) and pipelined (§4.4 chunk walk);
 * the Pallas fused-kernel path (``use_kernels=True``);
-* the coded r=2 XOR-multicast wire, plain and int8-quantized;
-* the int8-quantized uncoded wire;
 * the measured path (wave-timer stamps threaded through the same body,
   callback backend) in both sequential and pipelined form;
 * the fenced per-wave copy/run programs the measured-fallback and
@@ -24,8 +22,8 @@ dependency structure the checkers certify is the one XLA schedules.
 
 Plan targets come from the same host planner the job runs
 (:meth:`MapReduceJob._plan`) on synthetic-but-realistic statistics,
-including a straggler (Q||C_max) plan, a dead-slot plan, a coded r=2
-plan, and the sketch-statistics plans (pure count-min and the
+including a straggler (Q||C_max) plan, a dead-slot plan, and the
+sketch-statistics plans (pure count-min and the
 streaming-prefix two-step via :meth:`MapReduceJob._plan_prefixed`) whose
 snapshots exercise the analyzer's overestimate-aware capacity rules.
 A phase-A sketch target traces the provider collection step
@@ -63,7 +61,6 @@ class TracedTarget:
     name: str
     graph: jg.EqnGraph
     timed: bool = False
-    coded: bool = False
     pipelined: bool = False
 
 
@@ -78,13 +75,12 @@ def _shard_args():
     return inter, vec, vec, vec
 
 
-def _static(pipelined: bool, use_kernel: bool = False, replication: int = 1,
-            quantize: Optional[str] = None) -> Tuple:
+def _static(pipelined: bool, use_kernel: bool = False) -> Tuple:
     """The engine's ``cfg_static`` tuple for one variant."""
     chunks = CHUNKS if pipelined else 1
     caps = CHUNK_CAPS if pipelined else (CAPACITY,)
     return (M, N_CLUSTERS, CAPACITY, caps, "sum", pipelined, chunks,
-            use_kernel, replication, quantize)
+            use_kernel)
 
 
 def _trace_phase_b(static, timed: bool) -> jg.EqnGraph:
@@ -203,18 +199,6 @@ def phase_b_targets() -> List[TracedTarget]:
                      _trace_phase_b(_static(True, use_kernel=True),
                                     timed=False),
                      pipelined=True),
-        TracedTarget("pipelined-int8",
-                     _trace_phase_b(_static(True, quantize="int8"),
-                                    timed=False),
-                     pipelined=True),
-        TracedTarget("coded-r2",
-                     _trace_phase_b(_static(True, replication=2),
-                                    timed=False),
-                     coded=True, pipelined=True),
-        TracedTarget("coded-r2-int8",
-                     _trace_phase_b(_static(True, replication=2,
-                                            quantize="int8"), timed=False),
-                     coded=True, pipelined=True),
         TracedTarget("timed-sequential",
                      _trace_phase_b(_static(False), timed=True), timed=True),
         TracedTarget("timed-pipelined",
@@ -256,7 +240,7 @@ def _plan_for(cfg: mr.MapReduceConfig, seed: int) -> sc.CachedSchedule:
 
 
 def plan_targets() -> List[Tuple[str, sc.CachedSchedule]]:
-    """Real planner outputs across scheduler / speed / coding variants."""
+    """Real planner outputs across scheduler / speed / statistics variants."""
     out: List[Tuple[str, sc.CachedSchedule]] = []
     out.append(("lpt-uniform", _plan_for(
         mr.MapReduceConfig(num_slots=4, num_clusters=16, scheduler="lpt"),
@@ -270,9 +254,6 @@ def plan_targets() -> List[Tuple[str, sc.CachedSchedule]]:
     out.append(("lpt-dead-slot", _plan_for(
         mr.MapReduceConfig(num_slots=4, num_clusters=16, scheduler="lpt",
                            speeds=(1.0, 1.0, 0.0, 1.0)), seed=3)))
-    out.append(("coded-r2", _plan_for(
-        mr.MapReduceConfig(num_slots=4, num_clusters=16, scheduler="lpt",
-                           shuffle_replication=2), seed=4)))
     out.append(("sketch-os4m", _plan_for(
         mr.MapReduceConfig(num_slots=4, num_clusters=16, scheduler="os4m",
                            stats="sketch", sketch_width=64, sketch_depth=3),

@@ -84,9 +84,6 @@ from repro.core import stats_provider as sp
 
 AXIS = "mr_slots"
 
-# fp8 wire format needs a float8 dtype in this jax build; gated, not required.
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
-
 __all__ = ["MapReduceConfig", "JobResult", "MapReduceJob", "AXIS"]
 
 
@@ -144,30 +141,6 @@ class MapReduceConfig:
     # fault-tolerance mode, not the throughput path. Incompatible with
     # measured timings (which own the fenced program structure).
     checkpoint_waves: bool = False
-    # Coded shuffle (Coded MapReduce, arXiv 1512.01625): replicate each
-    # map shard r-way under a pair placement, then ship XOR multicast
-    # packets that serve two Reduce slots at once — phase B's measured
-    # bytes-on-the-wire drop by up to 2(m−1)/(m−2)× at r=2 while outputs
-    # stay bit-identical to the uncoded path (XOR decode is exact; the
-    # decoded stream is re-ordered to the uncoded (src, position) order
-    # before the same per-chunk reduce). r=1 is the uncoded engine;
-    # r=2 is the coded pair placement; the replica exchange's bytes are
-    # accounted separately (``JobResult.replication_bytes`` — in a real
-    # deployment they are redundant map *compute*, not shuffle traffic).
-    # Requires the fused executor: incompatible with ``checkpoint_waves``
-    # and with measured timings. See docs/SHUFFLE.md.
-    shuffle_replication: int = 1
-    # Optional lossy wire format for the shuffle payload: ``"int8"``
-    # (symmetric, one global psum-shared scale per batch — the
-    # train/compression.py error-feedback idiom, minus the feedback
-    # because shuffle values are one-shot) or ``"fp8"``
-    # (``float8_e4m3fn`` cast). Every delivered value — including a
-    # slot's own local pairs — goes through encode→decode, so coded and
-    # uncoded runs of the same quantized job remain bit-identical to
-    # each other. ``JobResult.quantize_exact`` reports whether the
-    # round-trip was lossless for this batch (integer-valued payloads
-    # within the dtype's exact range). None = exact f32/bf16 wire.
-    quantize_shuffle: Optional[str] = None
     # Pluggable statistics layer (docs/STATISTICS.md). "exact" plans from
     # the full (m, n) histogram K^(i) — bit-identical to the pre-provider
     # engine. "sketch" plans from a per-shard count-min sketch
@@ -227,14 +200,13 @@ class JobResult:
     speed_drift: Optional[float] = None  # slot-speed change vs the cached plan
     # Measured bytes-on-the-wire of phase B's shuffle (None on executors
     # that do not account — the checkpointed walk). Rows are counted on
-    # device (psum'd with the outputs); the host converts rows → bytes
-    # with the static wire row size, so the cost model and the replan
-    # gate see *measured* shuffle volume, not the modeled one.
-    shuffle_bytes: Optional[int] = None   # a2a payload bytes (packets once per multicast)
+    # device (psum'd with the outputs); each is one non-local pair, and
+    # the host converts rows → bytes with the static wire row size, so
+    # the cost model and the replan gate see *measured* shuffle volume,
+    # not the modeled one.
+    shuffle_bytes: Optional[int] = None   # a2a payload bytes
     shuffle_rows: Optional[int] = None    # wire rows behind those bytes
     shuffle_pairs: Optional[int] = None   # non-local pairs the wire carried
-    replication_bytes: int = 0            # coded replica-exchange bytes (not shuffle)
-    quantize_exact: Optional[bool] = None  # quantized round-trip lossless? (None = off)
     # keyed_output: the R keys that had a pair, one row of values and
     # counts each, in no particular order (None when rows are clusters).
     keys: Optional[np.ndarray] = None
@@ -583,6 +555,13 @@ def _sequential_reduce(
     )
 
 
+def _wire_vec(wire_rows):
+    """Phase B's wire vector: the rows that crossed the network (each a
+    non-local pair), psum'd so the host reads one ``(1,)`` vector on
+    either backend."""
+    return jax.lax.psum(wire_rows[None], AXIS)[None]
+
+
 def _fenced_wave_copy(fv, fc, fm, off: int, cap: int, num_slots: int,
                       v_dim: int):
     """The "copy" program of one fenced wave: slice its slab, all-to-all it.
@@ -603,334 +582,6 @@ def _fenced_wave_run(rv, rc, rm, rank_of_cluster, num_clusters: int,
     """The "sort"+"run" program of one fenced wave — shard-local reduce."""
     return _reduce_chunk(rv, rc, rm, rank_of_cluster, num_clusters,
                          reduce_op, use_kernel)
-
-
-def _wire_payload_dtype(quantize: Optional[str], value_dtype):
-    """The dtype actually serialized onto the shuffle wire."""
-    if quantize == "int8":
-        return jnp.int8
-    if quantize == "fp8":
-        return _FP8_DTYPE
-    return value_dtype
-
-
-def _quantize_scale(values, valid, quantize: Optional[str]):
-    """One global psum-shared int8 scale per batch (compression.py idiom).
-
-    A single scale — not per-chunk — so the sequential and pipelined
-    engines encode identically and stay bit-identical to each other.
-    """
-    if quantize != "int8":
-        return None
-    mag = jnp.max(
-        jnp.abs(values.astype(jnp.float32)) * valid.astype(jnp.float32)[:, None]
-    )
-    mag = jax.lax.pmax(mag, AXIS)
-    return jnp.maximum(mag, 1e-12) / 127.0
-
-
-def _quantize_encode(values, scale, quantize: str):
-    """values → wire payload (symmetric int8 or fp8 cast)."""
-    if quantize == "int8":
-        return jnp.clip(
-            jnp.round(values.astype(jnp.float32) / scale), -127, 127
-        ).astype(jnp.int8)
-    return values.astype(_FP8_DTYPE)
-
-
-def _quantize_decode(q, scale, value_dtype, quantize: str):
-    """Wire payload → delivered values (deterministic: one scale, one cast)."""
-    if quantize == "int8":
-        return (q.astype(jnp.float32) * scale).astype(value_dtype)
-    return q.astype(jnp.float32).astype(value_dtype)
-
-
-def _phase_b_shard_coded(
-    intermediate,
-    assignment: jnp.ndarray,
-    rank_of_cluster: jnp.ndarray,
-    chunk_of_cluster: jnp.ndarray,
-    cfg_static: Tuple,
-):
-    """Coded phase B: r=2 pair placement + XOR multicast (arXiv 1512.01625).
-
-    The coded execution of the same §4.4 chunk walk. Record ``j`` of slot
-    ``s`` is *pair-placed* on ``{s, π(s, j)}`` with partner
-    ``π(s, j) = (s + 1 + (j mod (m−1))) mod m`` — every slot holds a
-    replica of ``1/(m−1)`` of each other slot's shard, the coded analogue
-    of running each map shard on r=2 nodes. (Here the replicas arrive by
-    an intermediate all-to-all whose rows are accounted separately as
-    ``replication_bytes`` — a documented stand-in for HDFS-style storage
-    replication / redundant map compute, which is the scheme's premise.)
-
-    Shuffle then sends one XOR **multicast packet** per slot pair
-    ``{d, q}`` instead of two unicast slabs: the sender XORs its
-    (partner=d → dst=q) slab with its (partner=q → dst=d) slab word-wise
-    (``kernels/coded_shuffle``). Receiver ``d`` holds replicas of every
-    sender's partner-``d`` records, rebuilds the first slab with the
-    *identical* stable counting sort, and XORs it out — recovering the
-    slab addressed to it, bit-exactly. Pairs whose partner is their
-    destination ride the replica exchange for free, so wire rows shrink
-    by ``2(m−1)/(m−2)`` ≈ 2.3× at m=8 on a balanced workload.
-
-    Bit-identity with the uncoded engine: each slab row carries the
-    sender-local record index ``j`` (and its cluster id) beside the
-    packed value words; the receiver re-orders all delivered pairs by
-    ``(src_slot, j)`` — exactly the uncoded stream's per-cluster arrival
-    order — and feeds the SAME per-chunk ``_reduce_chunk``. Invalid rows
-    are all-zero words (XOR-neutral) and masked out.
-    """
-    from repro.kernels.coded_shuffle import ops as cs_ops
-
-    (num_slots, num_clusters, capacity, chunk_caps, reduce_op, pipelined,
-     num_chunks, use_kernel, replication, quantize) = cfg_static
-    del replication  # == 2, dispatched on
-    m, n = num_slots, num_clusters
-    key_hashes, values, valid = intermediate
-    k = key_hashes.shape[0]
-    v_dim = values.shape[-1]
-    v_dtype = values.dtype
-    cluster_ids = jnp.abs(key_hashes) % n
-    me = jax.lax.axis_index(AXIS)
-    dest = assignment[cluster_ids]
-
-    if pipelined and num_chunks > 1:
-        chunks = num_chunks
-        caps = tuple(chunk_caps)
-        chunk_of_pair = chunk_of_cluster[cluster_ids]
-    else:
-        chunks = 1
-        caps = (capacity,)
-        chunk_of_pair = jnp.zeros((k,), jnp.int32)
-    # Replica rows per (partner): each partner offset is hit every m−1
-    # records, so ⌈k/(m−1)⌉ bounds every (chunk, partner, dst) group —
-    # the coded slabs are usually much smaller than the uncoded buckets.
-    n_rep = -(-k // (m - 1))
-    cap2 = tuple(int(min(n_rep, caps[c])) for c in range(chunks))
-
-    # ---- Quantized wire payload (optional). One global scale (psum'd)
-    # so every slot — sender, replica holder, receiver — encodes the same
-    # record to the same bits; delivered values are the decoded ones for
-    # local pairs too, keeping coded ≡ uncoded under quantization.
-    if quantize:
-        scale = _quantize_scale(values, valid, quantize)
-        q_all = _quantize_encode(values, scale, quantize)
-        deq_all = _quantize_decode(q_all, scale, v_dtype, quantize)
-        inexact = jnp.sum(
-            valid & jnp.any(deq_all != values, axis=-1)
-        ).astype(jnp.float32)
-        wire_vals, deliv_vals = q_all, deq_all
-    else:
-        scale = None
-        inexact = jnp.zeros((), jnp.float32)
-        wire_vals, deliv_vals = values, values
-
-    wire_words = cs_ops.pack_payload_words(wire_vals)       # (k, W)
-    w_pay = wire_words.shape[-1]
-    w_row = w_pay + 2       # + cluster_id+1 word, + j+1 word (0 = invalid)
-    jidx = jnp.arange(k, dtype=jnp.int32)
-    aug = jnp.concatenate([
-        wire_words,
-        (cluster_ids.astype(jnp.int32) + 1)[:, None],
-        (jidx + 1)[:, None],
-    ], axis=1)
-
-    @jax.named_scope(spans.COPY)
-    def _a2a(x):
-        return jax.lax.all_to_all(
-            x, AXIS, split_axis=0, concat_axis=0, tiled=False
-        ).reshape(x.shape)
-
-    # ---- r=2 replica exchange: slot p receives my records with
-    # π(me, j) == p, i.e. j ≡ (p − me − 1) (mod m−1) — a strided slice.
-    partner = (me + 1 + (jidx % (m - 1))) % m
-    ofs_send = (jnp.arange(m) - me - 1) % m        # partner p ← offset row
-    tt = jnp.arange(n_rep)
-    sidx = ofs_send[:, None] + tt[None, :] * (m - 1)       # (m, n_rep)
-    smask = (sidx < k) & (ofs_send < m - 1)[:, None]       # row me: empty
-    sidx_c = jnp.minimum(sidx, k - 1)
-    r_kh = _a2a(jnp.where(smask, key_hashes[sidx_c], 0))
-    r_v = _a2a(jnp.where(smask[..., None], values[sidx_c], 0))
-    r_ok = _a2a(smask & valid[sidx_c])
-    ofs_recv = (me - jnp.arange(m) - 1) % m        # src s ← my offset at s
-    r_ok = r_ok & (ofs_recv < m - 1)[:, None]
-    r_j = (ofs_recv[:, None] + tt[None, :] * (m - 1)).astype(jnp.int32)
-    rows_rep = jnp.sum(r_ok.astype(jnp.float32))
-
-    r_cluster = jnp.abs(r_kh) % n
-    r_dest = assignment[r_cluster]
-    r_chunk = (chunk_of_cluster[r_cluster] if chunks > 1
-               else jnp.zeros_like(r_cluster))
-    r_flat_v = r_v.reshape(m * n_rep, v_dim)
-    r_wire = (_quantize_encode(r_flat_v, scale, quantize) if quantize
-              else r_flat_v)
-    r_aug = jnp.concatenate([
-        cs_ops.pack_payload_words(r_wire),
-        (r_cluster.reshape(-1).astype(jnp.int32) + 1)[:, None],
-        (r_j.reshape(-1) + 1)[:, None],
-    ], axis=1)
-
-    # ---- Two ragged spills with the SAME chunk-major group layout.
-    # Sender side: my own records by (chunk, partner, dst), dst ≠ me —
-    # these slabs are the packet XOR terms. Replica side: received
-    # replicas by (chunk, src, dst) — bit-equal reconstructions of each
-    # src's (partner=me, dst) slabs (same stable sort, same caps, same
-    # j order), used to XOR packets open; their dst=me column doubles as
-    # the replica-delivered pairs.
-    caps2_np = np.concatenate(
-        [np.full(m * m, cap2[c], np.int64) for c in range(chunks)]
-    )
-    total2 = int(caps2_np.sum())
-    gid = jnp.where(
-        valid & (dest != me),
-        (chunk_of_pair * m + partner) * m + dest,
-        chunks * m * m,
-    ).astype(jnp.int32)
-    s_aug, _s_bc, s_bm, ovf_send = _ragged_counting_sort_to_buckets(
-        gid, aug, cluster_ids.astype(jnp.int32), caps2_np, total2
-    )
-    src_of_row = jnp.repeat(jnp.arange(m), n_rep)
-    r_gid = jnp.where(
-        r_ok.reshape(-1),
-        (r_chunk.reshape(-1) * m + src_of_row) * m + r_dest.reshape(-1),
-        chunks * m * m,
-    ).astype(jnp.int32)
-    k_aug, _k_bc, _k_bm, ovf_rep = _ragged_counting_sort_to_buckets(
-        r_gid, r_aug, r_cluster.reshape(-1).astype(jnp.int32), caps2_np, total2
-    )
-
-    # ---- Pairs I both hold and reduce (dst == me): delivered locally,
-    # decoded-value payload, same j tag. (f32 carrier is exact for
-    # f32/bf16 payloads and the j index.)
-    caps_own = np.asarray(caps, np.int64)
-    total_own = int(caps_own.sum())
-    gid_own = jnp.where(valid & (dest == me), chunk_of_pair, chunks)
-    own_carrier = jnp.concatenate([
-        deliv_vals.astype(jnp.float32),
-        jidx.astype(jnp.float32)[:, None],
-    ], axis=1)
-    o_vals, o_bc, o_bm, ovf_own = _ragged_counting_sort_to_buckets(
-        gid_own.astype(jnp.int32), own_carrier,
-        cluster_ids.astype(jnp.int32), caps_own, total_own,
-    )
-
-    # ---- Per-chunk packet buffers: X[d, q] = S[p=d→q] ⊕ S[p=q→d], one
-    # multicast packet per unordered pair {d, q} (symmetric — both copies
-    # of the a2a row carry the same packet; accounted once below).
-    pay_dtype = _wire_payload_dtype(quantize, v_dtype)
-    dd = jnp.arange(m)[:, None]
-    qq = jnp.arange(m)[None, :]
-    pair_ok = (dd != qq) & (dd != me) & (qq != me)
-    send_pkts = []
-    wire_rows = jnp.zeros((), jnp.float32)
-    off = 0
-    for c in range(chunks):
-        size = m * m * cap2[c]
-        slab = s_aug[off:off + size].reshape(m, m, cap2[c], w_row)
-        slab_m = s_bm[off:off + size].reshape(m, m, cap2[c])
-        x = cs_ops.xor_words(
-            slab.reshape(-1, w_row),
-            jnp.swapaxes(slab, 0, 1).reshape(-1, w_row),
-            use_kernel=use_kernel,
-        ).reshape(m, m, cap2[c], w_row)
-        x = jnp.where(pair_ok[:, :, None, None], x, 0)
-        send_pkts.append(x)
-        # Packet {d,q} rows = max of its two slab counts; each unordered
-        # pair appears twice in the ordered sum, hence the /2.
-        cnt = jnp.sum(slab_m, axis=2).astype(jnp.float32)
-        wire_rows = wire_rows + jnp.sum(
-            jnp.where(pair_ok, jnp.maximum(cnt, cnt.T), 0.0)
-        ) / 2.0
-        off += size
-    pairs_nonlocal = jnp.sum(
-        (valid & (dest != me)).astype(jnp.float32)
-    )
-
-    # ---- Double-buffered decode→reduce walk (same §4.4 overlap shape:
-    # chunk c+1's packet all-to-all is issued before chunk c's reduce).
-    acc_dtype = jnp.float32 if (reduce_op == "sum" and use_kernel) else v_dtype
-    acc = jnp.zeros((n, v_dim), acc_dtype)
-    cnt_acc = jnp.zeros((n,), jnp.float32)
-    big = jnp.iinfo(jnp.int32).max
-    src_ids = jnp.broadcast_to(jnp.arange(m)[:, None, None], (m, m, 1))
-    q_ids = jnp.broadcast_to(jnp.arange(m)[None, :, None], (m, m, 1))
-    off = 0
-    own_off = 0
-    recv = _a2a(send_pkts[0])
-    for c in range(chunks):
-        rx = recv
-        if c + 1 < chunks:
-            recv = _a2a(send_pkts[c + 1])
-        size = m * m * cap2[c]
-        kc = k_aug[off:off + size].reshape(m, m, cap2[c], w_row)
-        # One XOR opens everything: for q ≠ me the packet minus my known
-        # slab leaves src's (partner=q → me) slab; the q == me column has
-        # no packet (zeros), so the XOR passes my replica-delivered slab
-        # (partner=me → me) straight through.
-        dec = cs_ops.xor_words(
-            rx.reshape(-1, w_row), kc.reshape(-1, w_row),
-            use_kernel=use_kernel,
-        ).reshape(m, m, cap2[c], w_row)
-        meta = dec[..., w_pay]
-        d_ok = (
-            (meta > 0)
-            & jnp.broadcast_to(src_ids != me, meta.shape)
-            & jnp.broadcast_to((q_ids == me) | (q_ids != src_ids), meta.shape)
-        )
-        d_vals = cs_ops.unpack_payload_words(
-            dec[..., :w_pay].reshape(-1, w_pay), pay_dtype, v_dim
-        )
-        if quantize:
-            d_vals = _quantize_decode(d_vals, scale, v_dtype, quantize)
-        else:
-            d_vals = d_vals.astype(v_dtype)
-        d_cl = (dec[..., w_pay] - 1).reshape(-1)
-        d_j = (dec[..., w_pay + 1] - 1).reshape(-1)
-        d_src = jnp.broadcast_to(
-            jnp.arange(m)[:, None, None], (m, m, cap2[c])
-        ).reshape(-1)
-
-        own = o_vals[own_off:own_off + caps[c]]
-        own_v = own[:, :v_dim].astype(v_dtype)
-        own_j = own[:, v_dim].astype(jnp.int32)
-        own_cl = o_bc[own_off:own_off + caps[c]]
-        own_ok = o_bm[own_off:own_off + caps[c]]
-        own_off += caps[c]
-
-        sv = jnp.concatenate([own_v, d_vals], axis=0)
-        scl = jnp.concatenate([own_cl, d_cl.astype(jnp.int32)])
-        sok = jnp.concatenate([own_ok, d_ok.reshape(-1)])
-        skey = jnp.concatenate([
-            me * k + own_j,
-            d_src.astype(jnp.int32) * k + d_j.astype(jnp.int32),
-        ])
-        # The uncoded stream orders each cluster's pairs by (src shard,
-        # bucket position) = (src, j); restore exactly that order so the
-        # SAME reduce accumulates the SAME sequence → bit-identity. The
-        # identical-sort wire contract demands explicit stability: sender
-        # and receiver must break equal keys the same way on every path.
-        order = jnp.argsort(jnp.where(sok, skey, big), stable=True)
-        out_c, cnt_c = _reduce_chunk(
-            sv[order], scl[order], sok[order], rank_of_cluster, n,
-            reduce_op, use_kernel,
-        )
-        if chunks == 1:
-            # Match the uncoded sequential branch exactly: the reduce
-            # output IS the result (shape included — count yields (n, 1)).
-            acc, cnt_acc = out_c, cnt_c
-        else:
-            if reduce_op == "max":
-                acc = jnp.where(
-                    cnt_c[:, None] > 0, out_c.astype(acc_dtype), acc)
-            else:
-                acc = acc + out_c.astype(acc_dtype)
-            cnt_acc = cnt_acc + cnt_c.astype(jnp.float32)
-        off += size
-
-    overflow = ovf_send + ovf_rep + ovf_own
-    wire = jnp.stack([wire_rows, rows_rep, inexact, pairs_nonlocal])
-    return (acc, cnt_acc, jax.lax.psum(overflow, AXIS)[None],
-            jax.lax.psum(wire, AXIS)[None])
 
 
 def _phase_b_shard(
@@ -970,60 +621,27 @@ def _phase_b_shard(
     slot, ``(keys, values)`` and counts, one row per received pair.
     ``counted``: the pairs are a combiner's, each with its count of map
     pairs as the last value column (``MapReduceConfig.combine``). Neither
-    is timed or coded.
+    is timed.
     """
     (num_slots, num_clusters, capacity, chunk_caps, reduce_op, pipelined,
-     num_chunks, use_kernel, replication, quantize) = cfg_static
-    if replication > 1:
-        # Coded pair placement (validated against stamp_through upstream:
-        # MapReduceJob.__init__ rejects coded × measured timings).
-        return _phase_b_shard_coded(
-            intermediate, assignment, rank_of_cluster, chunk_of_cluster,
-            cfg_static,
-        )
+     num_chunks, use_kernel) = cfg_static
     key_hashes, values, valid = intermediate
     v_dim = values.shape[-1]
     cluster_ids = jnp.abs(key_hashes) % num_clusters
     timed = stamp_through is not None
     me = jax.lax.axis_index(AXIS)
 
-    # Optional quantized wire: every pair — local ones included — is
-    # delivered as decode(encode(value)), so the wire format (not the
-    # routing) defines the outputs and coded runs can match bit-for-bit.
-    if quantize:
-        scale = _quantize_scale(values, valid, quantize)
-        send_vals = _quantize_encode(values, scale, quantize)
-        deq = _quantize_decode(send_vals, scale, values.dtype, quantize)
-        inexact = jnp.sum(
-            valid & jnp.any(deq != values, axis=-1)
-        ).astype(jnp.float32)
-    else:
-        scale = None
-        send_vals = values
-        inexact = jnp.zeros((), jnp.float32)
-
-    def _wire_vec(wire_rows):
-        # [a2a rows crossing the network, replica rows (coded only),
-        #  inexact quantized records, non-local pairs carried] — psum'd
-        # so the host reads one (4,) vector regardless of backend.
-        vec = jnp.stack([
-            wire_rows, jnp.zeros((), jnp.float32), inexact, wire_rows,
-        ])
-        return jax.lax.psum(vec, AXIS)[None]
-
     payload = key_hashes if keyed else cluster_ids.astype(jnp.int32)
     if not pipelined or num_chunks <= 1:
         dest = jnp.where(valid, assignment[cluster_ids], num_slots).astype(jnp.int32)
         bv, bc, bm, overflow = _counting_sort_to_buckets(
-            dest, send_vals, payload, num_slots, capacity
+            dest, values, payload, num_slots, capacity
         )
         # Bytes-on-the-wire: every bucketed row except the slot's own
         # diagonal bucket (delivered locally) crosses the network.
         wire_rows = (jnp.sum(bm.astype(jnp.float32))
                      - jnp.sum(bm[me].astype(jnp.float32)))
         rv, rc, rm = _copy_chunk((bv, bc, bm), v_dim)
-        if quantize:
-            rv = _quantize_decode(rv, scale, values.dtype, quantize)
         if keyed:
             keys, out, counts = _reduce_by_key(rc, rv, rm, reduce_op, counted)
             return ((keys, out), counts, jax.lax.psum(overflow, AXIS)[None],
@@ -1057,7 +675,7 @@ def _phase_b_shard(
     group_caps = np.repeat(np.asarray(chunk_caps, np.int64), num_slots)
     total = int(group_caps.sum())
     fv, fc, fm, overflow = _ragged_counting_sort_to_buckets(
-        group, send_vals, payload, group_caps, total
+        group, values, payload, group_caps, total
     )
     send = []
     wire_rows = jnp.zeros((), jnp.float32)
@@ -1095,8 +713,6 @@ def _phase_b_shard(
             # Issue chunk c+1's all-to-all BEFORE reducing chunk c (no
             # data edge from run(c) — nor, in timed mode, to any stamp).
             recv = _copy_chunk(send[c + 1], v_dim)
-        if quantize:
-            rv = _quantize_decode(rv, scale, values.dtype, quantize)
         if keyed:
             tables.append(_reduce_by_key(rc, rv, rm, reduce_op, counted))
             continue
@@ -1309,57 +925,11 @@ class MapReduceJob:
                     "measure timings nothing consumes"
                 )
         self._measure_timings = bool(measure)
-        # Coded / quantized shuffle: validated once, executed by the fused
-        # phase-B program only (the fenced and checkpointed executors have
-        # their own copy programs and raise instead of silently shipping
-        # an uncoded wire).
-        if cfg.shuffle_replication not in (1, 2):
-            raise ValueError(
-                "shuffle_replication must be 1 (uncoded) or 2 (coded pair"
-                f" placement), got {cfg.shuffle_replication}"
-            )
-        if cfg.quantize_shuffle not in (None, "int8", "fp8"):
-            raise ValueError(
-                f"quantize_shuffle must be None, 'int8' or 'fp8', got"
-                f" {cfg.quantize_shuffle!r}"
-            )
-        if cfg.quantize_shuffle == "fp8" and _FP8_DTYPE is None:
-            raise ValueError(
-                "quantize_shuffle='fp8' needs jnp.float8_e4m3fn, which this"
-                " jax build lacks — use 'int8' or None"
-            )
-        if cfg.shuffle_replication > 1:
-            if cfg.num_slots < 2:
-                raise ValueError(
-                    "shuffle_replication=2 needs at least 2 slots (the pair"
-                    " placement replicates across distinct slots)"
-                )
-            if cfg.checkpoint_waves:
-                raise ValueError(
-                    "shuffle_replication>1 is incompatible with"
-                    " checkpoint_waves — the checkpointed walk has its own"
-                    " per-wave copy programs; run coded jobs on the fused"
-                    " executor"
-                )
-            if self._measure_timings:
-                raise ValueError(
-                    "shuffle_replication>1 is incompatible with measured"
-                    " timings — the coded decode is not stamp-instrumented;"
-                    " set measure_timings=False to combine coding with speed"
-                    " estimation (synthetic model)"
-                )
-        if cfg.quantize_shuffle and cfg.checkpoint_waves:
-            raise ValueError(
-                "quantize_shuffle is incompatible with checkpoint_waves —"
-                " the checkpointed copy programs ship the exact wire"
-            )
         # The combiner and the per-key Reduce run on the fused jnp phase-B
         # executor only (the other executors reduce per cluster id).
         if cfg.combine or cfg.keyed_output:
             unsupported = {
                 "checkpoint_waves": cfg.checkpoint_waves,
-                "shuffle_replication > 1": cfg.shuffle_replication > 1,
-                "quantize_shuffle": cfg.quantize_shuffle is not None,
                 "measured timings": self._measure_timings,
                 "use_kernels": cfg.use_kernels,
                 # A prefix of the combined, key-sorted pairs is no sample of
@@ -1855,45 +1425,23 @@ class MapReduceJob:
         """Measured wire bytes per non-local pair (model default until measured).
 
         ``shuffle_bytes / shuffle_pairs`` of the last accounted batch: the
-        *effective* per-pair cost of the wire after coding and/or
-        quantization, which is what the flow-shop cost model's copy phase
-        should charge. Falls back to the simulator's modeled 64 B/pair.
+        per-pair cost of the wire, which is what the flow-shop cost
+        model's copy phase should charge. Falls back to the simulator's
+        modeled 64 B/pair.
         """
         if self._last_wire is not None and self._last_wire[1] > 0:
             return max(1e-6, self._last_wire[0] / self._last_wire[1])
         return 64.0
 
-    def _wire_accounting(self, wire_vec, values) -> dict:
-        """Convert the device row counters into bytes (static row sizes).
-
-        ``wire_vec`` is the psum'd ``[wire_rows, replica_rows, inexact,
-        nonlocal_pairs]`` vector phase B returns. Rows are measured on
-        device; the bytes per row are static properties of the wire
-        format: uncoded rows carry the payload (quantized or native) plus
-        a 4-byte cluster id; coded packet rows are XOR word slabs
-        (payload words + cluster word + position word); replica rows ship
-        the raw record (payload + 4-byte key hash).
-        """
-        rows, rep_rows, inexact, pairs = (float(x) for x in wire_vec)
-        cfg = self.cfg
-        v_dim = int(values.shape[-1])
-        v_dtype = jnp.dtype(values.dtype)
-        if cfg.shuffle_replication > 1:
-            from repro.kernels.coded_shuffle import ops as cs_ops
-
-            pay = _wire_payload_dtype(cfg.quantize_shuffle, v_dtype)
-            row_bytes = (cs_ops.packed_width(v_dim, pay) + 2) * 4
-        else:
-            vb = 1 if cfg.quantize_shuffle else v_dtype.itemsize
-            row_bytes = v_dim * vb + 4
-        rep_row_bytes = v_dim * v_dtype.itemsize + 4
-        return {
-            "shuffle_bytes": int(round(rows * row_bytes)),
-            "shuffle_rows": int(round(rows)),
-            "shuffle_pairs": int(round(pairs)),
-            "replication_bytes": int(round(rep_rows * rep_row_bytes)),
-            "inexact": int(round(inexact)),
-        }
+    @staticmethod
+    def _wire_accounting(rows: float, values) -> Tuple[int, int, int]:
+        """``(shuffle_bytes, shuffle_rows, shuffle_pairs)`` of ``rows``
+        measured wire rows: each row is one non-local pair, its ``V``
+        values plus a 4-byte cluster id (or key), so bytes = rows ×
+        (V·itemsize + 4)."""
+        row_bytes = int(values.shape[-1]) * jnp.dtype(values.dtype).itemsize + 4
+        n = int(round(rows))
+        return n * row_bytes, n, n
 
     # -- planning (the host "JobTracker" step) -------------------------------
 
@@ -1993,8 +1541,7 @@ class MapReduceJob:
                     pipelined=cfg.pipelined and pipeline_chunks > 1,
                     speeds=speeds,
                     # Measured wire rate (last batch) + per-slot locality: the
-                    # model sees what the shuffle actually costs, so coding or
-                    # quantizing the wire shifts strategy choice honestly.
+                    # model sees what the shuffle actually costs.
                     bytes_per_pair=self._wire_rate(),
                     # The locality-aware wire model wants per-shard (m, n)
                     # counts; a sketch densifies its estimates only for this
@@ -2083,8 +1630,7 @@ class MapReduceJob:
         # speeds — see ``pipeline.plan_waves``.
         waves = pipe.plan_waves(
             key_dist, schedule.assignment, m, pipeline_chunks,
-            speeds=speeds, replication=cfg.shuffle_replication,
-            pinned_first=pinned_first,
+            speeds=speeds, pinned_first=pinned_first,
         )
         chunk_caps = [
             int(min(capacity, _send_bound(waves.chunk_members(ci))))
@@ -2197,13 +1743,9 @@ class MapReduceJob:
         """
         cfg = self.cfg
         m, n = cfg.num_slots, cfg.num_clusters
-        # Replication rides the WAVE PLAN, not the config: a replayed
-        # snapshot executes with the wire format it was planned for (old
-        # uncoded snapshots keep running uncoded after a config change).
         static = (
             m, n, planned.capacity, tuple(planned.chunk_caps), cfg.reduce_op,
             cfg.pipelined, planned.waves.num_chunks, cfg.use_kernels,
-            planned.waves.replication, cfg.quantize_shuffle,
         )
 
         keyed, counted = cfg.keyed_output, cfg.combine
@@ -2269,7 +1811,6 @@ class MapReduceJob:
         static = (
             m, n, planned.capacity, tuple(planned.chunk_caps), cfg.reduce_op,
             cfg.pipelined, num_chunks, cfg.use_kernels,
-            planned.waves.replication, cfg.quantize_shuffle,
         )
         num_waves = num_chunks if cfg.pipelined and num_chunks > 1 else 1
 
@@ -2321,19 +1862,11 @@ class MapReduceJob:
         :meth:`_execute_measured`.
         """
         cfg = self.cfg
-        if planned.waves.replication > 1 or cfg.quantize_shuffle:
-            raise ValueError(
-                "the fenced measured fallback has its own copy programs and"
-                " does not implement the coded/quantized wire — disable"
-                " measure_timings (or provide a tick source) to run"
-                " shuffle_replication>1 / quantize_shuffle jobs"
-            )
         m, n = cfg.num_slots, cfg.num_clusters
         num_chunks = planned.waves.num_chunks
         static = (
             m, n, planned.capacity, tuple(planned.chunk_caps), cfg.reduce_op,
             cfg.pipelined, num_chunks, cfg.use_kernels,
-            planned.waves.replication, cfg.quantize_shuffle,
         )
         assignment = jnp.asarray(planned.schedule.assignment, jnp.int32)
         rank_of_cluster = jnp.asarray(planned.waves.rank_of_cluster)
@@ -2362,11 +1895,8 @@ class MapReduceJob:
                 me = jax.lax.axis_index(AXIS)
                 rows = (jnp.sum(bm.astype(jnp.float32))
                         - jnp.sum(bm[me].astype(jnp.float32)))
-                wire = jnp.stack(
-                    [rows, jnp.zeros(()), jnp.zeros(()), rows])
                 return (bv[None], bc[None], bm[None],
-                        jax.lax.psum(overflow, AXIS)[None],
-                        jax.lax.psum(wire, AXIS)[None])
+                        jax.lax.psum(overflow, AXIS)[None], _wire_vec(rows))
 
             def copy_fn(bv, bc, bm):
                 """The "copy": all-to-all every bucket to its Reduce slot."""
@@ -2421,10 +1951,8 @@ class MapReduceJob:
                 rows = rows + (jnp.sum(slab_m.astype(jnp.float32))
                                - jnp.sum(slab_m[me].astype(jnp.float32)))
                 off += m * cc
-            wire = jnp.stack([rows, jnp.zeros(()), jnp.zeros(()), rows])
             return (fv[None], fc[None], fm[None],
-                    jax.lax.psum(overflow, AXIS)[None],
-                    jax.lax.psum(wire, AXIS)[None])
+                    jax.lax.psum(overflow, AXIS)[None], _wire_vec(rows))
 
         fv, fc, fm, overflow, wire = self._run_sharded(
             spill_fn, ((0, 0, 0), None, None), (0, 0, 0, 0, 0),
@@ -2607,8 +2135,7 @@ class MapReduceJob:
             chunk_of_cluster = jnp.asarray(planned.waves.chunk_of_cluster)
             chunk_caps = tuple(planned.chunk_caps)
             static = (m, n, planned.capacity, chunk_caps, cfg.reduce_op,
-                      cfg.pipelined, num_chunks, cfg.use_kernels,
-                      planned.waves.replication, cfg.quantize_shuffle)
+                      cfg.pipelined, num_chunks, cfg.use_kernels)
             reduce_op, use_kernel = cfg.reduce_op, cfg.use_kernels
             group_caps = np.repeat(np.asarray(chunk_caps, np.int64), m)
             total = int(group_caps.sum())
@@ -2976,17 +2503,10 @@ class MapReduceJob:
             # model (``_wire_rate``), so the simulator charges the copy phase
             # what the wire actually cost, not the modeled 64 B/pair.
             shuffle_bytes = shuffle_rows = shuffle_pairs = None
-            replication_bytes = 0
-            quantize_exact = None
             if wire_vec is not None:
-                wv = np.asarray(wire_vec, np.float64).reshape(-1, 4)[0]
-                acct = self._wire_accounting(wv, intermediate[1])
-                shuffle_bytes = acct["shuffle_bytes"]
-                shuffle_rows = acct["shuffle_rows"]
-                shuffle_pairs = acct["shuffle_pairs"]
-                replication_bytes = acct["replication_bytes"]
-                if cfg.quantize_shuffle:
-                    quantize_exact = acct["inexact"] == 0
+                rows = float(np.asarray(wire_vec, np.float64).reshape(-1)[0])
+                shuffle_bytes, shuffle_rows, shuffle_pairs = (
+                    self._wire_accounting(rows, intermediate[1]))
                 self._last_wire = (shuffle_bytes, shuffle_pairs)
 
             # One Map operation per shard (paper footnote 1: Map task == operation).
@@ -3011,7 +2531,5 @@ class MapReduceJob:
                 shuffle_bytes=shuffle_bytes,
                 shuffle_rows=shuffle_rows,
                 shuffle_pairs=shuffle_pairs,
-                replication_bytes=replication_bytes,
-                quantize_exact=quantize_exact,
                 keys=keys,
             )

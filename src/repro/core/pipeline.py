@@ -114,8 +114,8 @@ class WavePlan:
     in per-wave load. The plan is pure host data (int32 numpy), cheap to
     snapshot in a :class:`repro.core.schedule_cache.CachedSchedule` and
     replay across batches without re-running ``plan_chunks``. The
-    structural invariants (permutation rank, dense one-shot chunk ids,
-    valid replication pairing) are certified statically by
+    structural invariants (permutation rank, dense one-shot chunk ids)
+    are certified statically by
     ``repro.analysis --check plan`` (see docs/ANALYSIS.md) on every real
     planner output, so an executor never has to re-derive them.
     """
@@ -123,13 +123,6 @@ class WavePlan:
     rank_of_cluster: np.ndarray   # (n,) int32
     chunk_of_cluster: np.ndarray  # (n,) int32
     num_chunks: int
-    # Coded-shuffle replication factor r (Coded MapReduce, arXiv
-    # 1512.01625): r = 1 is the plain unicast shuffle; r = 2 means map
-    # shards are pair-replicated and phase B ships XOR multicast packets
-    # (``kernels/coded_shuffle``) instead of per-destination slabs. The
-    # factor lives on the wave plan — not just the config — so a cached
-    # snapshot replays with the wire format it was planned for.
-    replication: int = 1
 
     def chunk_members(self, c: int) -> np.ndarray:
         """Cluster ids travelling in wave ``c``."""
@@ -141,18 +134,26 @@ class WavePlan:
             "rank_of_cluster": self.rank_of_cluster.tolist(),
             "chunk_of_cluster": self.chunk_of_cluster.tolist(),
             "num_chunks": int(self.num_chunks),
-            "replication": int(self.replication),
         }
 
     @staticmethod
     def from_json(d: Dict[str, Any]) -> "WavePlan":
-        """Rebuild a plan from :meth:`to_json` output (pre-coded snapshots
-        default to replication=1)."""
+        """Rebuild a plan from :meth:`to_json` output.
+
+        Older snapshots carry ``replication``, the factor of a coded wire
+        this engine no longer has: 1 (the one wire) loads, and any other
+        value raises rather than replaying on a wire it was not planned
+        for.
+        """
+        if int(d.get("replication", 1)) != 1:
+            raise ValueError(
+                f"wave plan has replication={d['replication']!r}; only the "
+                "uncoded wire (replication 1) exists"
+            )
         return WavePlan(
             rank_of_cluster=np.asarray(d["rank_of_cluster"], np.int32),
             chunk_of_cluster=np.asarray(d["chunk_of_cluster"], np.int32),
             num_chunks=int(d["num_chunks"]),
-            replication=int(d.get("replication", 1)),
         )
 
 
@@ -206,7 +207,6 @@ def plan_waves(
     num_chunks: int,
     order: str = "increasing",
     speeds: Optional[Sequence[float]] = None,
-    replication: int = 1,
     pinned_first: Optional[Sequence[int]] = None,
 ) -> WavePlan:
     """Cut a schedule into per-slot §4.4 waves and merge them into chunks.
@@ -227,11 +227,6 @@ def plan_waves(
     slot the speed is constant, so the per-slot wave cutting (and hence
     the chunk membership invariants) are unchanged; uniform speeds
     reproduce the load-ordered plan bit-identically.
-
-    ``replication`` is carried onto the plan as coded-shuffle metadata
-    (:class:`WavePlan` ``replication``); it does not change wave cutting
-    — coding changes the wire format of each wave's all-to-all, not
-    which clusters travel together.
 
     Degenerate inputs with ``num_chunks > n`` (more pipeline stages than
     operation clusters) are clamped to ``n`` with a one-time warning:
@@ -306,7 +301,6 @@ def plan_waves(
         rank_of_cluster=rank_of_cluster,
         chunk_of_cluster=chunk_of_cluster,
         num_chunks=max(1, len(used)),
-        replication=int(replication),
     )
 
 

@@ -279,8 +279,8 @@ def estimate_reduce_time(
     the node), instead of assuming every pair pays uniform network cost.
     ``bytes_per_pair`` may be a *measured* wire rate (e.g.
     ``JobResult.shuffle_bytes / shuffle_rows`` from the engine's
-    accounting layer), which is how quantized/coded shuffle modes keep
-    this cost model honest about the volume they actually ship.
+    accounting layer), which keeps this cost model honest about the
+    volume the wire actually ships.
     """
     loads = np.asarray(loads, dtype=np.float64)
     if speeds is None:

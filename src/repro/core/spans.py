@@ -67,7 +67,7 @@ COMBINE = "os4m.combine"  # also the device scope of the combiner's reduce by ke
 JIT_BUILD = "os4m.jit_build"
 
 SPILL = "os4m.spill"    # rank each pair in its (chunk, slot) group and bucket it
-COPY = "os4m.copy"      # the all-to-all of a chunk (and the coded path's exchanges)
+COPY = "os4m.copy"      # the all-to-all of a chunk
 REDUCE = "os4m.reduce"  # the segment reduce of a chunk, or of the whole input
 
 DEVICE_SCOPES = (SPILL, COPY, REDUCE, COMBINE)

@@ -10,7 +10,6 @@
 #   sketch_hist     — count-min compressed statistics (stats="sketch")
 #   segment_reduce  — the Reduce "sort"+"run" phase over bucket-file layout (§4.4)
 #   moe_dispatch    — the shuffle "copy": counting-sort of tokens by slot
-#   coded_shuffle   — XOR multicast encode/decode (Coded MapReduce, 1512.01625)
 #   flash_attention — keeps train_4k/prefill_32k compute-bound (roofline)
 
 import jax

@@ -110,9 +110,8 @@ def segment_reduce_sorted_pallas(
     outside ``[0, num_segments)`` are padding and contribute nothing.
     ``block_tokens`` is never shrunk to ``N``: the per-block matmul's f32
     association depends on the reduction length, so a fixed block keeps
-    outputs invariant to the padded slab length — two engine modes that
-    feed the same valid stream at different slab sizes (coded vs uncoded
-    shuffle) reduce bit-identically. ``interpret`` selects the Pallas
+    outputs invariant to the padded slab length — two callers that feed
+    the same valid stream at different slab sizes reduce bit-identically. ``interpret`` selects the Pallas
     interpreter (CPU) or Mosaic (TPU); the ``ops`` wrapper picks it from
     the backend.
     """

@@ -3,16 +3,15 @@
 Three layers, mirroring the analyzer's own proof obligations:
 
 * **Real targets are green** — every traced phase-B variant the repo
-  ships (vmap + shard_map, coded r=2, quantized, measured stamps, fenced
-  waves) and every real planner snapshot must produce zero findings: the
+  ships (vmap + shard_map, kernel path, measured stamps, fenced waves)
+  and every real planner snapshot must produce zero findings: the
   analyzer certifies the shipped engine, it does not cry wolf.
 * **Mutations are caught** — each seeded violation must be caught by the
   *intended* checker with the *intended* rule and non-empty evidence
   (an analyzer that has never failed anything proves nothing).
 * **Properties** — the plan validator accepts whatever the real planner
-  emits across random histograms, speed vectors (including dead slots),
-  and geometries where the replication factor does not divide the slot
-  count.
+  emits across random histograms and speed vectors (including dead
+  slots).
 """
 
 import io
@@ -58,7 +57,6 @@ class TestRealTargetsGreen:
         names = {t.name for t in phase_b}
         expected = {
             "sequential", "pipelined", "pipelined-kernels",
-            "pipelined-int8", "coded-r2", "coded-r2-int8",
             "timed-sequential", "timed-pipelined",
             "checkpointed-wave-copy", "checkpointed-wave-run",
         }
@@ -77,7 +75,7 @@ class TestRealTargetsGreen:
     def test_plans_clean(self, plans):
         names = {name for name, _ in plans}
         assert {"lpt-uniform", "os4m-pipelined", "lpt-straggler",
-                "lpt-dead-slot", "coded-r2"} <= names
+                "lpt-dead-slot"} <= names
         findings = plan_checks.check_plans(plans)
         assert findings == [], "\n".join(f.render() for f in findings)
 
@@ -91,14 +89,6 @@ class TestRealTargetsGreen:
         for t in timed:
             assert t.graph.by_prim("io_callback"), t.name
 
-    def test_coded_targets_contain_xor_and_stable_sorts(self, phase_b):
-        coded = [t for t in phase_b if t.coded]
-        assert coded, "no coded variants traced"
-        for t in coded:
-            assert t.graph.by_prim("xor"), t.name
-            sorts = t.graph.by_prim("sort")
-            assert sorts, t.name
-            assert all(n.eqn.params.get("is_stable") for n in sorts), t.name
 
 
 # ---------------------------------------------------------------------------
@@ -218,11 +208,10 @@ class TestReport:
 # ---------------------------------------------------------------------------
 
 
-def _snapshot(m, n, seed, speeds=None, chunks=1, replication=1):
+def _snapshot(m, n, seed, speeds=None, chunks=1):
     cfg = mr.MapReduceConfig(
         num_slots=m, num_clusters=n, scheduler="lpt",
-        pipeline_chunks=chunks, speeds=speeds,
-        shuffle_replication=replication)
+        pipeline_chunks=chunks, speeds=speeds)
     job = mr.MapReduceJob(lambda s: s, cfg)
     rng = np.random.default_rng(seed)
     hist = rng.integers(1, 64, size=(m, n)).astype(np.float64)
@@ -248,23 +237,6 @@ class TestPlanProperties:
         assert plan_checks.validate_snapshot(snap, "prop-dead") == []
         dead = seed % m
         assert not np.any(np.asarray(snap.schedule.assignment) == dead)
-
-    @pytest.mark.parametrize("m", [2, 3, 5, 7])
-    def test_pairing_valid_when_r_does_not_divide_m(self, m):
-        """π covers every other slot for any m >= 2 — including odd m,
-        where r=2 does not divide the slot count."""
-        assert plan_checks.validate_pairing(m, 2, f"m={m}") == []
-
-    def test_pairing_rejects_single_slot(self):
-        findings = plan_checks.validate_pairing(1, 2, "m=1")
-        assert [f.rule for f in findings] == ["invalid-pairing"]
-
-    @given(st.integers(3, 6), st.integers(8, 20), st.integers(0, 10_000))
-    @settings(max_examples=10, deadline=None)
-    def test_coded_plans_validate_clean(self, m, n, seed):
-        snap = _snapshot(m, n, seed, replication=2)
-        assert snap.waves.replication == 2
-        assert plan_checks.validate_snapshot(snap, "prop-coded") == []
 
 
 # ---------------------------------------------------------------------------

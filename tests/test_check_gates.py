@@ -127,40 +127,6 @@ class TestMultijobGate:
             check.gate_multijob(_write(tmp_path, r))
 
 
-GOOD_SHUFFLE = {
-    "uncoded": {"shuffle_bytes": 1_000_000, "shuffle_rows": 25_000,
-                "shuffle_pairs": 28_000, "wall_seconds": 0.04},
-    "coded": {"shuffle_bytes": 510_000, "shuffle_rows": 12_700,
-              "shuffle_pairs": 28_000, "replication_bytes": 1_100_000,
-              "wall_seconds": 0.17},
-    "bytes_reduction": 1.96,
-    "bit_identical": True,
-    "wall_ratio": 4.3,
-    "wall_ok": True,
-    "quantized": {"uncoded_bytes": 260_000, "coded_bytes": 300_000,
-                  "bit_identical": True, "exact": False},
-}
-
-
-class TestShuffleVolumeGate:
-    def test_good_report_passes(self, tmp_path, capsys):
-        check.gate_shuffle_volume(_write(tmp_path, GOOD_SHUFFLE))
-        assert "1.96x" in capsys.readouterr().out
-
-    @pytest.mark.parametrize("mutate", [
-        lambda r: r.update(bit_identical=False),
-        lambda r: r.update(bytes_reduction=1.2),
-        lambda r: r.update(wall_ok=False),
-        lambda r: r["coded"].update(replication_bytes=0),
-        lambda r: r["quantized"].update(bit_identical=False),
-    ])
-    def test_each_broken_field_fails(self, tmp_path, mutate):
-        r = copy.deepcopy(GOOD_SHUFFLE)
-        mutate(r)
-        with pytest.raises(check.GateFailure):
-            check.gate_shuffle_volume(_write(tmp_path, r))
-
-
 GOOD_SKETCH = {
     "plan_path": {"exact_seconds": 0.25, "sketch_seconds": 0.10,
                   "speedup": 2.5, "exact_pull_floats": 1_048_576,

@@ -225,8 +225,6 @@ def test_combiner_capacity_is_rerun_when_outgrown():
 
 UNSUPPORTED = {
     "checkpoint_waves": {"checkpoint_waves": True},
-    "coded": {"shuffle_replication": 2},
-    "quantized": {"quantize_shuffle": "int8"},
     "measured": {"measure_timings": True, "estimate_speeds": True},
     "use_kernels": {"use_kernels": True},
 }

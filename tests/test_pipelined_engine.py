@@ -10,6 +10,8 @@ Covers the PR's acceptance surface:
   and reports per-candidate cost estimates.
 """
 
+import warnings
+
 import numpy as np
 import pytest
 
@@ -137,6 +139,22 @@ def test_plan_chunks_partition_and_order(seed, num_chunks):
     # increasing-load order: within each chunk AND across chunk boundaries
     ordered = loads[flat]
     assert (np.diff(ordered) >= -1e-12).all()
+
+
+def test_plan_waves_clamps_excess_chunks_and_warns_once(monkeypatch):
+    monkeypatch.setattr(pipe, "_warned_excess_chunks", False)
+    loads = [4.0, 2.0, 1.0]
+    assign = np.array([0, 1, 0])
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        plan = pipe.plan_waves(loads, assign, 2, num_chunks=10)
+    assert plan.num_chunks <= 3              # clamped, no empty waves
+    assert (plan.chunk_of_cluster < plan.num_chunks).all()
+    assert len(caught) == 1 and "clamping" in str(caught[0].message)
+    with warnings.catch_warnings(record=True) as again:
+        warnings.simplefilter("always")
+        pipe.plan_waves(loads, assign, 2, num_chunks=10)
+    assert len(again) == 0                   # warn-once
 
 
 def test_plan_chunks_balances_load():

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.coded_shuffle.coded_shuffle import xor_words_pallas
 from repro.kernels.histogram.histogram import histogram_pallas
 from repro.kernels.segment_reduce.segment_reduce import segment_reduce_sorted_pallas
 from repro.kernels.sketch_hist.sketch_hist import sketch_hist_pallas
@@ -70,10 +69,6 @@ def _sketch(ids, w, mult):
     return sketch_hist_pallas(ids, w, mult, 1024, interpret=False)
 
 
-def _xor(a, b):
-    return xor_words_pallas(a, b, interpret=False)
-
-
 CASES = {
     "segment_reduce": (jax.vmap(_chunk_reduce),
                        [((SLOTS, ROWS, V), jnp.float32), ((SLOTS, ROWS), jnp.int32),
@@ -83,10 +78,6 @@ CASES = {
     "sketch_hist": (jax.vmap(_sketch, in_axes=(0, 0, None)),
                     [((SLOTS, PAIRS), jnp.int32), ((SLOTS, PAIRS), jnp.float32),
                      ((4,), jnp.uint32)]),
-    # Coded shuffle: (N, V + 2) int32 words — packed payload, cluster, meta.
-    "coded_shuffle": (jax.vmap(_xor),
-                      [((SLOTS, ROWS, V + 2), jnp.int32),
-                       ((SLOTS, ROWS, V + 2), jnp.int32)]),
 }
 
 
