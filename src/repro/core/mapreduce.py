@@ -398,47 +398,45 @@ def _ragged_counting_sort_to_buckets(
     group_caps: np.ndarray,  # (G,) static per-group capacities
     total: int,              # = group_caps.sum()
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One-pass counting sort into *ragged* fixed-capacity group buffers.
+    """Counting sort into *ragged* fixed-capacity group buffers.
 
     The pipelined engine's groups are (chunk, dest) pairs with
-    statistics-derived (hence unequal) capacities; a single stable sort
-    writes every chunk's bucket file in one spill, with chunk slabs
-    contiguous in the flat output. Returns flat ``(total, V)`` /
-    ``(total,)`` buffers + overflow count.
+    statistics-derived (hence unequal) capacities; one spill writes every
+    chunk's bucket file, with chunk slabs contiguous in the flat output.
+    One stable sort of the group ids carries each pair's index, so group
+    g's pairs are a run in stable order, and row ``j`` of g's slab holds
+    the ``(j - base[g])``-th pair of that run, where g has that many.
+    Each of the ``total`` rows gathers its own pair, so nothing as wide
+    as the pairs is gathered or scattered. Returns flat ``(total, V)`` /
+    ``(total,)`` buffers + the count of pairs past their group's capacity.
     """
     k = group.shape[0]
     num_groups = group_caps.shape[0]
     base = np.zeros(num_groups, np.int64)
     base[1:] = np.cumsum(group_caps)[:-1]
-    order = jnp.argsort(group, stable=True)
-    g_sorted = group[order]
-    idx = jnp.arange(k)
-    # A pair's rank in its group is its index less the group's start, and
+    base = jnp.asarray(base, jnp.int32)
+    caps = jnp.asarray(group_caps, jnp.int32)
+    g_sorted, order = jax.lax.sort(
+        (group, jnp.arange(k, dtype=jnp.int32)), num_keys=1, is_stable=True
+    )
     # g_sorted takes only G + 1 values: G + 1 searches in one compare-and-
     # count pass, where a search per pair loops over all K pairs.
     starts = jnp.searchsorted(
         g_sorted, jnp.arange(num_groups + 1, dtype=g_sorted.dtype),
         side="left", method="compare_all",
     )
-    pos = idx - starts[g_sorted]
-    g_clip = jnp.clip(g_sorted, 0, num_groups - 1)
-    cap_of = jnp.asarray(group_caps, jnp.int32)[g_clip]
-    in_range = g_sorted < num_groups
-    ok = in_range & (pos < cap_of)
-    overflow = jnp.sum(in_range & (pos >= cap_of))
-    flat = jnp.where(ok, jnp.asarray(base, jnp.int32)[g_clip] + pos, total)
-    v = values[order]
-    c = payload[order]
-    bucket_values = (
-        jnp.zeros((total + 1, values.shape[-1]), values.dtype)
-        .at[flat].set(jnp.where(ok[:, None], v, 0))[:-1]
-    )
-    bucket_clusters = (
-        jnp.full((total + 1,), -1, jnp.int32)
-        .at[flat].set(jnp.where(ok, c, -1))[:-1]
-    )
-    bucket_valid = jnp.zeros((total + 1,), jnp.bool_).at[flat].set(ok)[:-1]
-    return bucket_values, bucket_clusters, bucket_valid, overflow
+    counts = starts[1:] - starts[:-1]
+    overflow = jnp.sum(jnp.maximum(counts - caps, 0))
+    # Row j's group is the last whose slab starts at or before j (a group
+    # with no capacity shares its base with the next one).
+    row = jnp.arange(total, dtype=jnp.int32)
+    g_of_row = jnp.searchsorted(base, row, side="right", method="compare_all") - 1
+    pos = row - base[g_of_row]
+    ok = pos < counts[g_of_row]
+    src = order[jnp.where(ok, starts[g_of_row] + pos, 0)]
+    bucket_values = jnp.where(ok[:, None], values[src], 0)
+    bucket_clusters = jnp.where(ok, payload[src], -1)
+    return bucket_values, bucket_clusters, ok, overflow
 
 
 def _segment_reduce(cluster_ids, values, valid, num_clusters: int, reduce_op: str,
@@ -2309,6 +2307,19 @@ class MapReduceJob:
         return TraceAnnotation(spans.PLAN, valid_pairs=int(np.sum(key_dist)),
                                input_pairs=self.cfg.num_slots * int(k_per_shard))
 
+    def _bucket_rows(self, planned: sc.CachedSchedule) -> int:
+        """Rows one shard's spill writes under ``planned``: ``m`` buckets of
+        each chunk's capacity, or of the one capacity when not pipelined."""
+        m = self.cfg.num_slots
+        if self.cfg.pipelined and planned.waves.num_chunks > 1:
+            return m * sum(planned.chunk_caps)
+        return m * planned.capacity
+
+    def _phase_b_span(self, planned: sc.CachedSchedule) -> TraceAnnotation:
+        """The ``os4m.phase_b`` span, with the rows a shard's spill writes."""
+        return TraceAnnotation(spans.PHASE_B,
+                               bucket_rows=self._bucket_rows(planned))
+
     # -- public API ----------------------------------------------------------
 
     def run(self, inputs) -> JobResult:
@@ -2407,7 +2418,7 @@ class MapReduceJob:
         def execute(planned):
             """Phase B under ``planned``: device results and the overflow count."""
             timings = None
-            with TraceAnnotation(spans.PHASE_B):
+            with self._phase_b_span(planned):
                 if measured:
                     out, counts, overflow, wire_vec, timings = (
                         self._execute_measured(intermediate, planned))
@@ -2419,7 +2430,7 @@ class MapReduceJob:
 
         if checkpointing:
             self.last_replay_plan = None
-            with TraceAnnotation(spans.PHASE_B):
+            with self._phase_b_span(planned):
                 values, counts_np, overflow_total = self._execute_checkpointed(
                     intermediate, planned, local_k, k_per_shard)
         else:
@@ -2446,7 +2457,7 @@ class MapReduceJob:
             if checkpointing:
                 # Mid-batch kills already fired during the first walk, so
                 # this re-execution is a clean checkpointed pass.
-                with TraceAnnotation(spans.PHASE_B):
+                with self._phase_b_span(planned):
                     values, counts_np, overflow_total = self._execute_checkpointed(
                         intermediate, planned, local_k, k_per_shard)
             else:

@@ -17,7 +17,7 @@ stats of the trace event (the counters below). One batch is one tree:
       os4m.plan (valid_pairs, input_pairs)
         os4m.plan.assign                the scheduler (hash / sketch / OS4M / auto)
           os4m.plan.bss                 OS4M's BSS DP, where LPT is not certified
-      os4m.phase_b                      each dispatch of a phase-B executor
+      os4m.phase_b (bucket_rows)        each dispatch of a phase-B executor
         os4m.jit_build (key)            first call of a newly built executable
       os4m.output_pull (bytes)          overflow count, outputs and counts, wire vector
       os4m.merge                        slot merge, wire accounting, speed feedback
@@ -25,8 +25,12 @@ stats of the trace event (the counters below). One batch is one tree:
 ``batch`` is the job's running batch index; ``bytes`` the bytes pulled to
 the host; ``valid_pairs`` the pairs the statistics count (sum of the key
 distribution) and ``input_pairs`` the slots' map outputs (m x k), so their
-ratio is the share of the spilled pairs that are real; ``key`` the first
-element of the jit-cache key (``"a"`` phase A, ``"b"`` phase B, ...).
+ratio is the share of the spilled pairs that are real; ``bucket_rows``
+the rows one shard's spill writes under the plan phase B runs (m x the
+sum of the chunk capacities; m x the capacity when not pipelined), so
+its ratio to ``input_pairs`` / m is the share of a shard's pairs the
+spill touches past its sort; ``key`` the first element of the jit-cache
+key (``"a"`` phase A, ``"b"`` phase B, ...).
 ``os4m.jit_build`` also opens inside ``os4m.batch`` for phase A and inside
 ``os4m.combine`` for the combiner. ``os4m.combine`` opens only under
 ``MapReduceConfig(combine=True)``: ``capacity`` is the static per-shard
@@ -66,7 +70,7 @@ MERGE = "os4m.merge"
 COMBINE = "os4m.combine"  # also the device scope of the combiner's reduce by key
 JIT_BUILD = "os4m.jit_build"
 
-SPILL = "os4m.spill"    # rank each pair in its (chunk, slot) group and bucket it
+SPILL = "os4m.spill"    # sort pairs by (chunk, slot) group; gather each bucket row
 COPY = "os4m.copy"      # the all-to-all of a chunk
 REDUCE = "os4m.reduce"  # the segment reduce of a chunk, or of the whole input
 

@@ -74,10 +74,16 @@ def record(backend: str, log_dir: str) -> dict:
 
     job = _job(backend)
     a, drifted = _batch(0, 1.3), _batch(1, 3.0)
+    reused, plans = [], []
     with jax.profiler.trace(log_dir):
-        reused = [job.run(b).reused for b in (a, a, drifted)]
+        for b in (a, a, drifted):
+            reused.append(job.run(b).reused)
+            plan = job.schedule_cache.snapshot
+            plans.append({"chunks": plan.waves.num_chunks,
+                          "chunk_caps": list(plan.chunk_caps),
+                          "capacity": plan.capacity})
     return {"events": _host_events(log_dir), "reused": reused,
-            "jit_misses": job.jit_misses}
+            "jit_misses": job.jit_misses, "plans": plans}
 
 
 def _recorded(backend: str) -> dict:
@@ -138,6 +144,18 @@ def test_replanned_batch_nests_every_step(traced, which):
     assert all(e[3]["bytes"] > 0 for e in pulls)
     # the statistics pull is the (m, n) float32 histogram
     assert inner[names.index("os4m.stats_pull")][3]["bytes"] == M * N * 4
+
+
+def test_phase_b_span_counts_the_bucket_rows(traced):
+    """Each ``os4m.phase_b`` span carries the rows one shard's spill
+    writes, m x the plan's chunk capacities; a reused batch replays the
+    cold plan's."""
+    rows = [next(e for e in _inside(traced, b) if e[0] == "os4m.phase_b")[3]
+            ["bucket_rows"] for b in _batches(traced)]
+    want = [M * (sum(p["chunk_caps"]) if p["chunks"] > 1 else p["capacity"])
+            for p in traced["plans"]]
+    assert rows == want
+    assert traced["plans"][0]["chunks"] > 1 and rows[1] == rows[0]
 
 
 def test_reused_batch_has_no_plan(traced):
